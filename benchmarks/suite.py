@@ -643,7 +643,7 @@ def bench_fp_mesh(smoke: bool = False) -> dict:
     # the workload draws), not per-shard: the r05 TPU run of the old
     # per-shard=2^16 config on a 1-device mesh left the table 8× under
     # water — permanent window pressure, sweep+grow cycles inside the
-    # timed loop, 14.6K dec/s (RESULTS.md r05 suite table).
+    # timed loop.
     total_slots = 1 << (11 if smoke else 19)
     store = ShardedFpDeviceStore(
         mesh, capacity=1e9, fill_rate_per_sec=1.0,
@@ -685,11 +685,6 @@ CONFIGS = {
 
 
 def main(argv: list[str] | None = None) -> int:
-    from distributedratelimiting.redis_tpu.utils.cpu_bootstrap import (
-        maybe_force_cpu_from_env,
-    )
-
-    maybe_force_cpu_from_env()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("configs", nargs="*",
                         help=f"subset of configs to run (default: all); "

@@ -22,11 +22,12 @@ HBM-bandwidth-bound and fuse naturally.
 
 The count vector is tiny (N/TILE int32), so the host can decide whether a
 10M-slot sweep freed anything by fetching ~KBs instead of a 10 MB bool
-mask — on remote/tunneled links that is the difference between a no-op
-sweep costing one small readback and costing a bulk transfer.
+mask: a no-op sweep costs one small readback, not a bulk transfer.
 
-Falls back to interpret mode off-TPU so the same code path is unit-tested
-on the CPU mesh (tests/conftest.py).
+The caller picks the mode: compiled Mosaic on a TPU (the store's only
+sweep there; a failure raises), or ``interpret=True``, which runs the
+same kernel in Pallas's interpreter on the CPU for the tests. Nothing
+here falls back on its own.
 """
 
 from __future__ import annotations

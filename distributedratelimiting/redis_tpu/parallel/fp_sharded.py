@@ -26,16 +26,16 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-from distributedratelimiting.redis_tpu.parallel._shard_compat import (
-    pcast_varying,
-    shard_map,
-)
 
 from distributedratelimiting.redis_tpu.ops import bucket_math as bm
 from distributedratelimiting.redis_tpu.ops import fp_directory as F
 from distributedratelimiting.redis_tpu.ops import kernels as K
-from distributedratelimiting.redis_tpu.parallel.mesh import SHARD_AXIS
+from distributedratelimiting.redis_tpu.parallel.mesh import (
+    SHARD_AXIS,
+    init_sharded,
+)
 from distributedratelimiting.redis_tpu.parallel.sharded_store import (
     GlobalCounter,
     global_tier_update,
@@ -185,7 +185,8 @@ def make_sharded_fp_scan_step(mesh, *, probe_window: int = 16,
 
         # The accumulator is per-shard ("varying" over the mesh axis inside
         # shard_map); the initial zero must be cast to match.
-        zero = pcast_varying(jnp.zeros((), jnp.float32), SHARD_AXIS)
+        zero = jax.lax.pcast(jnp.zeros((), jnp.float32), (SHARD_AXIS,),
+                             to="varying")
         ((fp, state, gcounter, consumed_total), out) = jax.lax.scan(
             body, (fp, state, gcounter, zero), (fused[0], nows))
         if deferred:
@@ -271,7 +272,7 @@ class ShardedFpDeviceStore:
 
         fp_shard = NamedSharding(mesh, P(SHARD_AXIS, None))
         n = per_shard_slots * self.n_shards
-        self.fp = jax.device_put(F.init_fp_table(n), fp_shard)
+        self.fp = init_sharded(F.init_fp_table, n, fp_shard)
         self.state = self._fresh_sharded_state(n)
         self.gcounter = jax.device_put(
             init_global_counter(), NamedSharding(mesh, P()))
@@ -282,9 +283,8 @@ class ShardedFpDeviceStore:
         return K.init_bucket_state(n)
 
     def _fresh_sharded_state(self, n: int):
-        shard = NamedSharding(self.mesh, P(SHARD_AXIS))
-        st = self._init_state_host(n)
-        return type(st)(*(jax.device_put(a, shard) for a in st))
+        return init_sharded(self._init_state_host, n,
+                            NamedSharding(self.mesh, P(SHARD_AXIS)))
 
     def _make_step(self):
         return make_sharded_fp_scan_step(
@@ -467,7 +467,7 @@ class ShardedFpDeviceStore:
         for _attempt in range(4):
             n = per_new * self.n_shards
             fp_shard = NamedSharding(self.mesh, P(SHARD_AXIS, None))
-            fp = jax.device_put(F.init_fp_table(n), fp_shard)
+            fp = init_sharded(F.init_fp_table, n, fp_shard)
             state = self._fresh_sharded_state(n)
             pending = entries
             stuck = False

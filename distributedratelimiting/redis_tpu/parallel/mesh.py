@@ -13,7 +13,8 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["SHARD_AXIS", "create_mesh", "shard_spec", "replicated_spec"]
+__all__ = ["SHARD_AXIS", "create_mesh", "shard_spec", "replicated_spec",
+           "init_sharded"]
 
 SHARD_AXIS = "shard"
 
@@ -40,3 +41,10 @@ def shard_spec(mesh: Mesh) -> NamedSharding:
 
 def replicated_spec(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+def init_sharded(init, n: int, sharding):
+    """Build ``init(n)``'s arrays directly in ``sharding``: each device
+    materialises only its own shard. (``jax.device_put`` of a freshly
+    built array would first hold all of it on one device.)"""
+    return jax.jit(init, static_argnums=0, out_shardings=sharding)(n)

@@ -119,8 +119,8 @@ class MeshBucketStore(BucketStore):
         # Global-tier psum cadence for the sharded bucket tiers: "batch"
         # (K collectives per scanned launch, counter staleness ≤ one
         # batch) or "launch" (ONE collective per launch, staleness ≤ one
-        # launch's span, ~+22% bulk throughput measured —
-        # docs/OPERATIONS.md §3, benchmarks/RESULTS.md "Psum cadence").
+        # launch's span; docs/OPERATIONS.md §3 — the throughput
+        # difference on chips is not measured).
         self.sync_cadence = sync_cadence
         # Key-directory home for the sharded keyed tiers (buckets +
         # windows): "host" = per-shard native C tables; "fp" = the

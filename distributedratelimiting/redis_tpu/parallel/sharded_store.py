@@ -25,15 +25,15 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from distributedratelimiting.redis_tpu.parallel._shard_compat import (
-    pcast_varying,
-    shard_map,
-)
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributedratelimiting.redis_tpu.ops import bucket_math as bm
 from distributedratelimiting.redis_tpu.ops import kernels as K
-from distributedratelimiting.redis_tpu.parallel.mesh import SHARD_AXIS
+from distributedratelimiting.redis_tpu.parallel.mesh import (
+    SHARD_AXIS,
+    init_sharded,
+)
 from distributedratelimiting.redis_tpu.runtime.clock import Clock, MonotonicClock
 from distributedratelimiting.redis_tpu.runtime.directory import make_directory
 from distributedratelimiting.redis_tpu.runtime.store import (
@@ -275,8 +275,8 @@ def make_two_level_scan_step_deferred(mesh, *, handle_duplicates: bool = True):
     returned counter's decay granularity: one ``Δt·decay`` step at the last
     batch's ``now`` instead of K steps — staleness bounded by one launch's
     time span, exactly the reference's staleness ≤ period bound with
-    "period" = launch cadence. Measured trade: benchmarks/RESULTS.md
-    "Psum cadence ablation".
+    "period" = launch cadence. Its throughput effect on chips is not
+    measured.
     """
     state_specs = K.BucketState(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS))
     gspecs = GlobalCounter(P(), P(), P(), P())
@@ -296,7 +296,8 @@ def make_two_level_scan_step_deferred(mesh, *, handle_duplicates: bool = True):
 
         # The accumulator is per-shard ("varying" over the mesh axis inside
         # shard_map); the initial zero must be cast to match.
-        zero = pcast_varying(jnp.zeros((), jnp.float32), SHARD_AXIS)
+        zero = jax.lax.pcast(jnp.zeros((), jnp.float32), (SHARD_AXIS,),
+                             to="varying")
         (state, consumed_total), (granted, remaining) = jax.lax.scan(
             body, (state, zero),
             (slots[0], counts[0], valid[0], nows),
@@ -655,19 +656,14 @@ class ShardedDeviceStore(_ShardedKeyedTable):
 
         n_total = self.n_shards * per_shard_slots
         sharding = NamedSharding(mesh, P(SHARD_AXIS))
-        self.state = K.BucketState(
-            tokens=jax.device_put(jnp.zeros((n_total,), jnp.float32), sharding),
-            last_ts=jax.device_put(jnp.zeros((n_total,), jnp.int32), sharding),
-            exists=jax.device_put(jnp.zeros((n_total,), bool), sharding),
-        )
+        self.state = init_sharded(K.init_bucket_state, n_total, sharding)
         self.gcounter = jax.device_put(
             init_global_counter(), NamedSharding(mesh, P())
         )
         self._step = make_two_level_step(mesh,
                                          handle_duplicates=handle_duplicates)
-        # Global-tier sync cadence (deployable form of the RESULTS.md
-        # "Psum cadence ablation", +22% measured on the virtual mesh):
-        # "batch" = one psum per scanned batch (K collectives/launch,
+        # Global-tier sync cadence (its throughput effect on chips is not
+        # measured): "batch" = one psum per scanned batch (K collectives/launch,
         # counter staleness ≤ one batch); "launch" = consumed counts
         # accumulate in-scan and ONE psum lands after it (staleness ≤ one
         # launch's time span — the reference's staleness ≤ period bound
@@ -948,13 +944,7 @@ class ShardedWindowStore(_ShardedKeyedTable):
         self._rebase_threshold = rebase_threshold_ticks
         n_total = self.n_shards * per_shard_slots
         sharding = NamedSharding(mesh, P(SHARD_AXIS))
-        init = K.init_window_state(n_total)
-        self.state = K.WindowState(
-            prev_count=jax.device_put(init.prev_count, sharding),
-            curr_count=jax.device_put(init.curr_count, sharding),
-            window_idx=jax.device_put(init.window_idx, sharding),
-            exists=jax.device_put(init.exists, sharding),
-        )
+        self.state = init_sharded(K.init_window_state, n_total, sharding)
         self._scan_step = make_sharded_window_scan_step(
             mesh, interpolate=not fixed,
             handle_duplicates=handle_duplicates)

@@ -19,9 +19,9 @@ passthrough frames and are served by the same
 :mod:`~.wire` stays the single protocol authority for those shapes.
 
 Why this exists: the per-request serving ceiling of the asyncio socket
-path is ~13K req/s/core even with a zero-cost kernel — per-request
-framing plus task scheduling, measured in benchmarks/RESULTS.md
-("Per-request socket ceiling isolated"). The reference's answer to that
+path was ~13K req/s/core even with a zero-cost kernel on a builder's
+CPU host — per-request framing plus task scheduling (not measured on
+the chip's host). The reference's answer to that
 class of cost is the Redis *server* — a C epoll loop. This is that
 component for the TPU store.
 """
@@ -1424,8 +1424,8 @@ class NativeFrontend:
         predates the uring ABI): shard counts by transport, ring
         counters (enter syscalls, SQEs submitted, CQEs reaped), the
         self-instrumented data-plane syscall counter both transports
-        maintain (the ``syscalls/frame`` numerator in
-        benchmarks/RESULTS.md §r16), and per-shard fallback reasons
+        maintain (the ``syscalls/frame`` numerator), and per-shard
+        fallback reasons
         when uring was requested but a shard serves on epoll."""
         if not getattr(self._lib, "has_uring", False) or self._h is None:
             return None
@@ -1587,8 +1587,7 @@ def native_loadgen(host: str, port: int, *, conns: int = 4, depth: int = 32,
     fixed_window / sema) in flight. Returns ``(replies, granted,
     elapsed_s)``. Runs in C (one epoll thread) so a Python client's
     ~14µs/request scheduling floor doesn't bound the measurement — the
-    asymmetric rig the per-request ceiling analysis called for
-    (benchmarks/RESULTS.md)."""
+    asymmetric rig the per-request ceiling analysis called for."""
     if op not in _LOADGEN_OPS:
         raise ValueError(
             f"unknown loadgen op {op!r}; choose from "

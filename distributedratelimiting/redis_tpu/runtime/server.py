@@ -120,9 +120,8 @@ class BucketStoreServer:
         self.port = port
         # Native front-end (native/frontend.cc): the C++ epoll loop owns
         # the sockets and hands per-request micro-batches to Python once
-        # per flush — the serving path's answer to the measured ~13K
-        # req/s/core asyncio per-request ceiling (benchmarks/RESULTS.md
-        # "Per-request socket ceiling isolated").
+        # per flush — the serving path's answer to the asyncio
+        # per-request ceiling (~13K req/s/core on a builder's CPU host).
         self.native_frontend = native_frontend
         # The C batcher's own knobs (≙ the store micro-batcher's
         # max_batch/max_delay_s, OPERATIONS.md §3): flush size cap and
@@ -223,9 +222,8 @@ class BucketStoreServer:
         # Server-side serving latency: request decoded (arrival) →
         # result ready (before the reply hits the socket). This is the
         # latency the FRAMEWORK is accountable for — client-observed
-        # latency adds the network RTT, which on a tunneled test link
-        # swamps it (benchmarks/RESULTS.md p99 decomposition). Exposed
-        # via OP_STATS as serving_p50_ms/serving_p99_ms.
+        # latency adds the network RTT. Exposed via OP_STATS as
+        # serving_p50_ms/serving_p99_ms.
         self.serving_latency = LatencyHistogram()
         # Reply stage (result ready → reply handed to the transport):
         # with the store's queue/flush histograms this completes the
@@ -610,8 +608,8 @@ class BucketStoreServer:
             "store", "store metrics",
             lambda: metrics.snapshot() if metrics is not None else None,
             counters={"launches", "rows_processed", "rows_valid", "sweeps",
-                      "slots_evicted", "pallas_sweep_failures",
-                      "rows_coalesced", "pregrows", "fp_unresolved"})
+                      "slots_evicted", "rows_coalesced", "pregrows",
+                      "fp_unresolved"})
         reg.register_numeric_dict(
             "tier0", "tier-0 admission cache",
             lambda: (self._native.tier0_stats()
@@ -2279,12 +2277,6 @@ def main(argv: list[str] | None = None) -> None:
     """
     import argparse
 
-    from distributedratelimiting.redis_tpu.utils.cpu_bootstrap import (
-        maybe_force_cpu_from_env,
-    )
-
-    maybe_force_cpu_from_env()
-
     parser = argparse.ArgumentParser(description="TPU bucket-store server")
     parser.add_argument("--host", default="127.0.0.1")
     parser.add_argument("--port", type=int, default=6380)
@@ -2465,6 +2457,11 @@ def main(argv: list[str] | None = None) -> None:
                         "decided level for gateways to poll "
                         "(docs/OPERATIONS.md §13)")
     args = parser.parse_args(argv)
+    from distributedratelimiting.redis_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     if args.fe_tier0 and not args.native_frontend:
         parser.error("--fe-tier0 requires --native-frontend (the tier-0 "
                      "admission cache lives inside the C front-end)")

@@ -1,9 +1,8 @@
 """Flight recorder — a bounded ring of recent serving-state frames that
 dumps itself to JSONL when things go wrong.
 
-The r04/r05 outage windows were diagnosed from prose (RESULTS.md
-"degraded window" notes): by the time anyone looked, the state that
-explained the window — flush sizes and latencies leading in, sync
+Outage windows diagnosed from prose lose their evidence: by the time
+anyone looks, the state that explained the window — flush sizes and latencies leading in, sync
 failure streaks, carry growth — was gone. This module keeps the last N
 frames in memory at ~zero cost (one dict append per flush/sync round)
 and writes them out the moment a degraded-mode trigger fires, so the

@@ -48,7 +48,7 @@ class LatencyHistogram:
     (a quantile reports its bucket's upper edge) — the old √2 base's ±41%
     was too coarse exactly where the <2ms p99 north star lives (the
     0.5-16ms decade spans ~15 buckets now vs ~10 before at twice the
-    width; VERDICT r4 weak #2). Still O(1) memory and allocation-free
+    width). Still O(1) memory and allocation-free
     recording.
 
     Exemplars: ``record(seconds, trace_id=...)`` (or :meth:`exemplar`)
@@ -258,10 +258,6 @@ class StoreMetrics:
     rows_valid: int = 0
     sweeps: int = 0
     slots_evicted: int = 0
-    # Pallas streaming-sweep fallbacks: nonzero means the compiled Mosaic
-    # path failed on this platform and sweeps silently use the XLA kernel —
-    # the bench asserts this stays 0 on real TPU.
-    pallas_sweep_failures: int = 0
     # Duplicate requests merged away by flush coalescing (requests minus
     # launch rows) — the Zipf hot-key win's direct measure.
     rows_coalesced: int = 0
@@ -274,7 +270,7 @@ class StoreMetrics:
     # Wall time of each micro-batch flush (dispatch + device kernel +
     # readback, measured inside MicroBatcher._run_flush). Serving p99
     # minus flush p99 is the framework's own queueing/fan-out share —
-    # the decomposition the <2ms north star needs (VERDICT r4 #3b).
+    # the decomposition the <2ms north star needs.
     flush_latency: LatencyHistogram = field(default_factory=LatencyHistogram)
     # Stage 1 of the per-request decomposition: enqueue → flush dispatch,
     # recorded once per flush for the OLDEST request in the batch (its
@@ -305,7 +301,6 @@ class StoreMetrics:
             "batch_occupancy": self.batch_occupancy,
             "sweeps": self.sweeps,
             "slots_evicted": self.slots_evicted,
-            "pallas_sweep_failures": self.pallas_sweep_failures,
             "rows_coalesced": self.rows_coalesced,
             "pregrows": self.pregrows,
             "fp_unresolved": self.fp_unresolved,

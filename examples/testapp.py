@@ -33,6 +33,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import os
 import subprocess
 import sys
 import time
@@ -180,6 +181,8 @@ def cmd_convergence(args) -> int:
                 [sys.executable, __file__, "worker", "--port", str(port),
                  "--id", str(i), "--seconds", str(args.seconds)],
                 cwd=REPO_ROOT, stdout=subprocess.PIPE, text=True,
+                # Workers reach the store over TCP and need no chip.
+                env=dict(os.environ, JAX_PLATFORMS="cpu"),
             )
             for i in range(args.instances)
         ]
@@ -381,14 +384,14 @@ def main(argv: list[str] | None = None) -> int:
     p.set_defaults(fn=cmd_cluster)
 
     args = parser.parse_args(argv)
+    from distributedratelimiting.redis_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     return args.fn(args)
 
 
 if __name__ == "__main__":
     sys.path.insert(0, REPO_ROOT)
-    from distributedratelimiting.redis_tpu.utils.cpu_bootstrap import (
-        maybe_force_cpu_from_env,
-    )
-
-    maybe_force_cpu_from_env()
     sys.exit(main())

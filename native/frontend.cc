@@ -14,9 +14,9 @@
 // 8 serves OP_ACQUIRE_MANY natively too: parse, per-row tier-0
 // decisions, and the RESP_BULK encode all run here, with only the
 // cold-row residue crossing the ABI as one zero-copy batch. The
-// measured per-request asyncio ceiling this replaces is ~13K req/s/core
-// with a zero-cost kernel (benchmarks/RESULTS.md "Per-request socket
-// ceiling isolated"); everything that ceiling charges per request
+// per-request asyncio ceiling this replaces was ~13K req/s/core with a
+// zero-cost kernel on a builder's CPU host (not measured on the chip's
+// host); everything that ceiling charges per request
 // (readexactly, task spawn, decode, encode, write lock) runs here in C.
 //
 // Threading: one IO thread (epoll) owns all sockets. Python's pump
@@ -33,7 +33,7 @@
 //   has waited deadline_us (timerfd, ns precision — asyncio timers
 //   quantize ~1ms), or (c) the pump is idle and nothing is queued
 //   (flush-on-idle: batching only pays when a flush is already in
-//   flight; benchmarks/RESULTS.md "flush-on-idle" halved p50).
+//   flight).
 
 #include <arpa/inet.h>
 #include <fcntl.h>
@@ -2249,7 +2249,7 @@ void io_loop(Shard* sh) {
       // (ready empty AND inflight empty): batching only pays when a
       // flush is already running — while one is, arrivals accumulate so
       // the batch size adapts to load (same reasoning as MicroBatcher's
-      // flush-on-idle, benchmarks/RESULTS.md).
+      // flush-on-idle).
       bool idle_pump = sh->pump_waiting && sh->ready.empty() &&
                        sh->pt.empty() && sh->inflight.empty() &&
                        sh->bulk_ready.empty() && sh->bulk_inflight.empty();
@@ -4046,8 +4046,7 @@ int fe_hot_harvest(void* h, char* key_blob, int blob_cap, int32_t* klens,
 // ---------------------------------------------------------------------
 // Native closed-loop load generator: the measurement client for the
 // front-end (a Python client's own ~14µs/request scheduling floor would
-// bound the measurement, not the server — benchmarks/RESULTS.md
-// "Per-request socket ceiling"). Opens `conns` connections, keeps
+// bound the measurement, not the server). Opens `conns` connections, keeps
 // `depth` ACQUIRE requests in flight on each, counts grants. Single
 // epoll thread; returns total replies, grants, and elapsed seconds.
 // ---------------------------------------------------------------------

@@ -91,7 +91,6 @@ class TestMeshBackendCLI:
 
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   DRLT_FORCE_CPU_PLATFORM="1",
                    XLA_FLAGS=f"{XLA_DEVICE_COUNT_FLAG}=8")
         proc = subprocess.Popen(
             [sys.executable, "-u", "-m",
@@ -137,8 +136,7 @@ class TestMeshBackendCLI:
         import sys
 
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   DRLT_FORCE_CPU_PLATFORM="1")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         proc = subprocess.Popen(
             [sys.executable, "-u", "-m",
              "distributedratelimiting.redis_tpu.runtime.server",

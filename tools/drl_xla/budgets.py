@@ -42,8 +42,8 @@ __all__ = [
 
 #: The budgeted keys. launches/gather/scatter/while/sort are the
 #: artifact-shape ratchet; operands/results price host↔device transfer
-#: count per launch (operand COUNT, not bytes, dominates on tunneled
-#: links — ops/kernels.py's own contract).
+#: count per launch (ops/kernels.py's own contract; their cost on the
+#: chip is not measured).
 BUDGET_KEYS = ("launches", "gather", "scatter", "while", "sort",
                "operands", "results")
 
@@ -53,7 +53,7 @@ def ledger_path(root: pathlib.Path) -> pathlib.Path:
 
 
 def _subjaxprs(eqn):
-    from jax import core
+    from jax.extend import core
     for v in eqn.params.values():
         vals = v if isinstance(v, (list, tuple)) else (v,)
         for x in vals:
