@@ -303,6 +303,10 @@ class NativeFrontend:
                 "binary predates the fe_bulk ABI; ACQUIRE_MANY stays on "
                 "the passthrough lane")
         self._hot_task: asyncio.Task | None = None
+        # The fe_handoff stage (C stamp -> the loop's first line), kept
+        # in Python beside the C side's native_queue/native_exec.
+        self.handoff_latency = LatencyHistogram()
+        self._stage_stamps = getattr(lib, "has_stage_stamps", False)
         if self._bulk_native:
             hh = getattr(server, "heavy_hitters", None)
             # One call arms the lane on EVERY shard (the C side fans
@@ -391,11 +395,13 @@ class NativeFrontend:
                 break
             try:
                 if kind == 1:
-                    self._dispatch_batch(sh)
+                    with tracing.annotate("drl/fe.pump"):
+                        self._dispatch_batch(sh)
                 elif kind == 2:
                     self._dispatch_passthrough(sh)
                 elif kind == 3:
-                    self._dispatch_bulk(sh)
+                    with tracing.annotate("drl/fe.pump"):
+                        self._dispatch_bulk(sh)
             except Exception as exc:  # noqa: BLE001 — the pump is the one
                 # thread every connection on its shard depends on: it
                 # must survive any single bad batch/frame (the items get
@@ -427,6 +433,7 @@ class NativeFrontend:
         n = lib.fe_batch_n(h)
         if n <= 0:
             return
+        t_cut_ns = lib.fe_batch_t_cut_ns(h) if self._stage_stamps else 0
         kb = lib.fe_batch_key_bytes(h)
         blob = ctypes.create_string_buffer(max(int(kb), 1))
         klens = np.empty(n, np.int32)
@@ -473,7 +480,8 @@ class NativeFrontend:
                 tr_fl.ctypes.data_as(c.POINTER(c.c_uint8)))
             traces = (tr_hi, tr_lo, tr_par, tr_fl)
         self._track(self._serve_batch(sh, bid, keys, counts, ops, a_arr,
-                                      b_arr, traces, seqs, conn_ids))
+                                      b_arr, traces, seqs, conn_ids,
+                                      t_cut_ns))
 
     def _dispatch_passthrough(self, sh: int) -> None:
         lib, h = self._lib, sh
@@ -499,6 +507,7 @@ class NativeFrontend:
         jid = int(u[0])
         if jid == 0:
             return
+        t_arrival_ns = lib.fe_bulk_t_ns(h) if self._stage_stamps else 0
         n, blob_len, res_n = int(u[4]), int(u[5]), int(u[6])
         ptrs = np.zeros(4, np.uint64)
         lib.fe_bulk_ptrs(h, ptrs.ctypes.data_as(c.POINTER(c.c_uint64)))
@@ -522,7 +531,7 @@ class NativeFrontend:
         self._track(self._serve_bulk(
             sh, jid, int(u[1]), int(u[2]), int(u[3]), float(f[0]),
             float(f[1]), wire.KeyBlob(blob, offsets), counts, residue,
-            tctx))
+            tctx, t_arrival_ns))
 
     # -- loop-side serving -------------------------------------------------
 
@@ -530,7 +539,9 @@ class NativeFrontend:
                            counts: np.ndarray, ops: np.ndarray,
                            a_arr: np.ndarray, b_arr: np.ndarray,
                            traces=None, seqs: np.ndarray | None = None,
-                           conn_ids: np.ndarray | None = None) -> None:
+                           conn_ids: np.ndarray | None = None,
+                           t_cut_ns: int = 0) -> None:
+        self._record_handoff(t_cut_ns)
         n = len(keys)
         t_start = time.perf_counter()
         pgate = full = None
@@ -770,15 +781,16 @@ class NativeFrontend:
                                 int(ps.pmap.node_of(keys[i])))))
                         g_full[i] = _ROW_SKIP
                 granted, remaining = g_full, r_full
-            if traces is not None:
-                self._record_batch_spans(traces, granted, ops, t_start)
-            c = ctypes
-            self._lib.fe_complete(
-                sh, bid,
-                np.ascontiguousarray(granted).ctypes.data_as(
-                    c.POINTER(c.c_uint8)),
-                np.ascontiguousarray(remaining).ctypes.data_as(
-                    c.POINTER(c.c_double)))
+            with tracing.annotate("drl/fe.complete"):
+                if traces is not None:
+                    self._record_batch_spans(traces, granted, ops, t_start)
+                c = ctypes
+                self._lib.fe_complete(
+                    sh, bid,
+                    np.ascontiguousarray(granted).ctypes.data_as(
+                        c.POINTER(c.c_uint8)),
+                    np.ascontiguousarray(remaining).ctypes.data_as(
+                        c.POINTER(c.c_double)))
         except Exception as exc:  # noqa: BLE001 — every request must get
             log.error_evaluating_kernel(exc)  # a routable error reply
             if traces is not None:
@@ -789,6 +801,15 @@ class NativeFrontend:
                     traces, None, ops if full is None else full[3],
                     t_start)
             self._lib.fe_fail(sh, bid, repr(exc)[:200].encode())
+
+    def _record_handoff(self, t0_ns: int) -> None:
+        """The ``fe_handoff`` stage: from the C side's stamp (batch cut,
+        bulk arrival; 0 from a binary without the stamp accessors) to
+        this first line on the loop — the pump's wake-up, copy and key
+        decode, and the loop's queue. Both clocks are CLOCK_MONOTONIC."""
+        if t0_ns:
+            self.handoff_latency.record(
+                (time.perf_counter_ns() - t0_ns) * 1e-9)
 
     def _record_batch_spans(self, traces, granted, ops: np.ndarray,
                             t_start: float) -> None:
@@ -823,7 +844,8 @@ class NativeFrontend:
     async def _serve_bulk(self, sh: int, jid: int, conn_id: int,
                           seq: int, flags: int, a: float, b: float,
                           keys: "wire.KeyBlob", counts: np.ndarray,
-                          residue: np.ndarray, tctx=None) -> None:
+                          residue: np.ndarray, tctx=None,
+                          t_arrival_ns: int = 0) -> None:
         """Loop half of the native bulk lane: decide the residue rows
         the C side could not (cold keys, windows, probes), mirroring the
         asyncio server's ACQUIRE_MANY branch gate for gate — config,
@@ -835,6 +857,7 @@ class NativeFrontend:
         that then hits a gate stay debited through the sync/retire lane
         — the documented ≤-one-interval epsilon family, same as the
         scalar lanes' commit races."""
+        self._record_handoff(t_arrival_ns)
         srv = self._server
         lib, h = self._lib, sh
         n = len(keys)
@@ -932,12 +955,13 @@ class NativeFrontend:
                             granted[p] = g
                             remaining[p] = rem
             c = ctypes
-            lib.fe_bulk_complete(
-                h, jid,
-                np.ascontiguousarray(granted).ctypes.data_as(
-                    c.POINTER(c.c_uint8)),
-                np.ascontiguousarray(remaining).ctypes.data_as(
-                    c.POINTER(c.c_double)))
+            with tracing.annotate("drl/fe.complete"):
+                lib.fe_bulk_complete(
+                    h, jid,
+                    np.ascontiguousarray(granted).ctypes.data_as(
+                        c.POINTER(c.c_uint8)),
+                    np.ascontiguousarray(remaining).ctypes.data_as(
+                        c.POINTER(c.c_double)))
         except Exception as exc:  # noqa: BLE001 — every frame must get
             log.error_evaluating_kernel(exc)  # a routable error reply
             lib.fe_bulk_fail(h, jid, repr(exc)[:200].encode())
@@ -1501,10 +1525,13 @@ class NativeFrontend:
         return hist
 
     def stage_histograms(self) -> "dict[str, LatencyHistogram] | None":
-        """The C side's per-stage decomposition of the serving span:
-        ``queue`` (frame parsed → batch cut) and ``exec`` (batch cut →
-        completion = Python dispatch + store + kernel). ``None`` when the
-        loaded binary predates the stage-hist ABI."""
+        """The per-stage decomposition of the native serving span: the C
+        side's ``native_queue`` (frame parsed → batch cut) and
+        ``native_exec`` (batch cut → completion), then the host segments
+        of exec: ``fe_handoff`` (batch cut or bulk arrival → the loop's
+        first line) and the store's ``store_host`` / ``store_readback``
+        (when its metrics keep them). ``None`` when the loaded binary
+        predates the stage-hist ABI."""
         if not getattr(self._lib, "has_stage_hist", False):
             return None
         out: dict[str, LatencyHistogram] = {}
@@ -1513,10 +1540,23 @@ class NativeFrontend:
             if hist is None:
                 return None
             out[name] = hist
+        if self._stage_stamps:
+            out["fe_handoff"] = self.handoff_latency
+        out.update(self._store_stages())
         return out
 
+    def _store_stages(self) -> "dict[str, LatencyHistogram]":
+        metrics = getattr(self._server.store, "metrics", None)
+        return {name: hist for name in ("store_host", "store_readback")
+                if (hist := getattr(metrics, name, None)) is not None}
+
     def reset_latency(self) -> None:
+        """Start a measurement window: the serving histogram and every
+        stage of :meth:`stage_histograms`."""
         self._lib.fe_hist_reset(self._h)  # stage hists reset with it
+        self.handoff_latency.reset()
+        for hist in self._store_stages().values():
+            hist.reset()
 
     async def aclose(self) -> None:
         if self._stopping:

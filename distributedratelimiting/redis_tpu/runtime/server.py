@@ -596,7 +596,14 @@ class BucketStoreServer:
                       "Per-stage share of the serving span",
                       lambda: self.reply_latency,
                       labels={"stage": "reply"})
-        for stage in ("native_queue", "native_exec"):
+        # The store call's host stages (per call, any front end), then
+        # the native front end's C stages and its pump -> loop hand-off.
+        for stage in ("store_host", "store_readback"):
+            reg.histogram("stage_latency_seconds",
+                          "Per-stage share of the serving span",
+                          lambda s=stage: getattr(metrics, s, None),
+                          labels={"stage": stage})
+        for stage in ("native_queue", "native_exec", "fe_handoff"):
             reg.histogram(
                 "stage_latency_seconds",
                 "Per-stage share of the serving span",
@@ -1367,10 +1374,11 @@ class BucketStoreServer:
                     self.reply_latency.reset()
                     metrics = getattr(self.store, "metrics", None)
                     if metrics is not None:
-                        if hasattr(metrics, "flush_latency"):
-                            metrics.flush_latency.reset()
-                        if hasattr(metrics, "queue_latency"):
-                            metrics.queue_latency.reset()
+                        for name in ("flush_latency", "queue_latency",
+                                     "store_host", "store_readback"):
+                            hist = getattr(metrics, name, None)
+                            if hist is not None:
+                                hist.reset()
             elif op == wire.OP_METRICS:
                 resp = wire.encode_response(
                     seq, wire.RESP_TEXT, self.registry.render())
@@ -2180,6 +2188,8 @@ class BucketStoreServer:
         stage("queue", getattr(metrics, "queue_latency", None))
         stage("flush", getattr(metrics, "flush_latency", None))
         stage("reply", self.reply_latency)
+        stage("store_host", getattr(metrics, "store_host", None))
+        stage("store_readback", getattr(metrics, "store_readback", None))
         if self._native is not None:
             for name, hist in (self._native.stage_histograms()
                                or {}).items():

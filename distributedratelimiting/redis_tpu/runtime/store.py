@@ -29,6 +29,7 @@ from __future__ import annotations
 import abc
 import asyncio
 import threading
+import time
 from typing import Callable, Iterable, NamedTuple, Sequence
 
 import jax
@@ -42,7 +43,11 @@ from distributedratelimiting.redis_tpu.runtime.batcher import MicroBatcher
 from distributedratelimiting.redis_tpu.runtime.clock import Clock, MonotonicClock
 from distributedratelimiting.redis_tpu.runtime.directory import make_directory
 from distributedratelimiting.redis_tpu.utils.metrics import StoreMetrics
-from distributedratelimiting.redis_tpu.utils.tracing import Profiler, ProfilingSession
+from distributedratelimiting.redis_tpu.utils.tracing import (
+    Profiler,
+    ProfilingSession,
+    annotate,
+)
 
 __all__ = [
     "AcquireResult",
@@ -742,19 +747,19 @@ class _PackedLaunchMixin:
     async def _flush(self, reqs: Sequence[_AcquireReq]) -> list[AcquireResult]:
         groups = (self._coalesce(reqs)
                   if self.store.coalesce_duplicates else None)
-        loop = asyncio.get_running_loop()
         # Block for device results on an executor thread so the event loop
         # keeps accumulating the next flush; readbacks of distinct flushes
         # overlap (see MicroBatcher). One packed array = one transfer.
+        t0 = time.perf_counter()
+        out = (self._launch(reqs) if groups is None
+               else self._dispatch_grouped(groups))
+        self.store.metrics.store_host.record(time.perf_counter() - t0)
+        out_np = await self._readback(lambda: np.asarray(out))
         if groups is None:
-            out = self._launch(reqs)
-            out_np = await loop.run_in_executor(None, lambda: np.asarray(out))
             return [
                 AcquireResult(bool(out_np[0, i] > 0.5), float(out_np[1, i]))
                 for i in range(len(reqs))
             ]
-        out = self._dispatch_grouped(groups)
-        out_np = await loop.run_in_executor(None, lambda: np.asarray(out))
         results: list[AcquireResult | None] = [None] * len(reqs)
         for g, (_, count, _, members, _) in enumerate(groups):
             n_granted = int(out_np[0, g])
@@ -769,6 +774,22 @@ class _PackedLaunchMixin:
                     granted,
                     max(avail - j * count - (count if granted else 0), 0.0))
         return results  # type: ignore[return-value]
+
+    async def _readback(self, fetch: Callable[[], object]):
+        """Run ``fetch`` (device→host transfer and unpack) on the loop's
+        executor under the ``drl/store.readback`` span; records the
+        ``store_readback`` stage from the submit to the host arrays in
+        hand on the executor thread (the loop's wake-up after it is not
+        counted)."""
+        def job():
+            with annotate("drl/store.readback"):
+                out = fetch()
+            return out, time.perf_counter()
+
+        t0 = time.perf_counter()
+        out, t1 = await asyncio.get_running_loop().run_in_executor(None, job)
+        self.store.metrics.store_readback.record(t1 - t0)
+        return out
 
     @staticmethod
     def _coalesce(reqs: Sequence[_AcquireReq]):
@@ -804,18 +825,19 @@ class _PackedLaunchMixin:
                                       len(groups)), self.store._lock:
             slots = self.resolve_slots([g[0] for g in groups])
             b = self.store.max_batch
-            now = self.store.now_ticks_checked()
-            packed = np.full((5, b), -1, np.int32)
-            packed[1] = 0
-            packed[3] = 0
-            packed[4] = 0
-            n = len(groups)
-            packed[0, :n] = slots
-            packed[1, :n] = [g[1] for g in groups]
-            packed[2] = now
-            packed[3, :n] = [g[4] for g in groups]
-            packed[4, :n] = [g[2] for g in groups]
-            out = self._launch_grouped(jnp.asarray(packed))
+            with annotate("drl/store.dispatch"):
+                now = self.store.now_ticks_checked()
+                packed = np.full((5, b), -1, np.int32)
+                packed[1] = 0
+                packed[3] = 0
+                packed[4] = 0
+                n = len(groups)
+                packed[0, :n] = slots
+                packed[1, :n] = [g[1] for g in groups]
+                packed[2] = now
+                packed[3, :n] = [g[4] for g in groups]
+                packed[4, :n] = [g[2] for g in groups]
+                out = self._launch_grouped(jnp.asarray(packed))
             n_reqs = sum(g[2] for g in groups)
             self.store.metrics.record_launch(b, n)
             self.store.metrics.rows_coalesced += n_reqs - n
@@ -891,25 +913,27 @@ class _PackedLaunchMixin:
             # keys may be a wire.KeyBlob: the native directory resolves
             # straight from the frame's byte blob (zero Python strings).
             slots = self.resolve_slots(keys)
-            now = self.store.now_ticks_checked()
-            pos = 0
-            while pos < n:
-                rows = -(-(n - pos) // b)  # ceil
-                k = 1
-                while k < rows and k < self._BULK_MAX_K:
-                    k *= 2
-                take = min(k * b, n - pos)
-                s = np.full((k * b,), -1, np.int32)
-                s[:take] = slots[pos:pos + take]
-                c = np.zeros((k * b,), np.uint8 if compact else np.int32)
-                c[:take] = np.minimum(counts_np[pos:pos + take], 2**31 - 1)
-                nows = np.full((k,), now, np.int32)
-                out = self._launch_scan_chunk(
-                    s.reshape(k, b), c.reshape(k, b), nows, compact,
-                    with_remaining)
-                outs.append((out, take))
-                self.store.metrics.record_launch(k * b, take)
-                pos += take
+            with annotate("drl/store.dispatch"):
+                now = self.store.now_ticks_checked()
+                pos = 0
+                while pos < n:
+                    rows = -(-(n - pos) // b)  # ceil
+                    k = 1
+                    while k < rows and k < self._BULK_MAX_K:
+                        k *= 2
+                    take = min(k * b, n - pos)
+                    s = np.full((k * b,), -1, np.int32)
+                    s[:take] = slots[pos:pos + take]
+                    c = np.zeros((k * b,), np.uint8 if compact else np.int32)
+                    c[:take] = np.minimum(counts_np[pos:pos + take],
+                                          2**31 - 1)
+                    nows = np.full((k,), now, np.int32)
+                    out = self._launch_scan_chunk(
+                        s.reshape(k, b), c.reshape(k, b), nows, compact,
+                        with_remaining)
+                    outs.append((out, take))
+                    self.store.metrics.record_launch(k * b, take)
+                    pos += take
         return outs
 
     def _launch_scan_chunk(self, s: np.ndarray, c: np.ndarray,
@@ -1000,21 +1024,23 @@ class _PackedLaunchMixin:
             gslots = slots[order][starts]
             gcounts = np.minimum(first_c, 2**31 - 1).astype(np.int32)
             b = self.store.max_batch
-            now = self.store.now_ticks_checked()
             outs: list[tuple] = []
-            for pos in range(0, len(gslots), b):
-                m = min(b, len(gslots) - pos)
-                packed = np.full((5, b), -1, np.int32)
-                packed[1] = 0
-                packed[3] = 0  # one group per slot per call ⇒ prefix 0
-                packed[4] = 0
-                packed[0, :m] = gslots[pos:pos + m]
-                packed[1, :m] = gcounts[pos:pos + m]
-                packed[2] = now
-                packed[4, :m] = np.minimum(lengths[pos:pos + m], 2**31 - 1)
-                out = self._launch_grouped(jnp.asarray(packed))
-                outs.append((out, m))
-                self.store.metrics.record_launch(b, m)
+            with annotate("drl/store.dispatch"):
+                now = self.store.now_ticks_checked()
+                for pos in range(0, len(gslots), b):
+                    m = min(b, len(gslots) - pos)
+                    packed = np.full((5, b), -1, np.int32)
+                    packed[1] = 0
+                    packed[3] = 0  # one group per slot per call ⇒ prefix 0
+                    packed[4] = 0
+                    packed[0, :m] = gslots[pos:pos + m]
+                    packed[1, :m] = gcounts[pos:pos + m]
+                    packed[2] = now
+                    packed[4, :m] = np.minimum(lengths[pos:pos + m],
+                                               2**31 - 1)
+                    out = self._launch_grouped(jnp.asarray(packed))
+                    outs.append((out, m))
+                    self.store.metrics.record_launch(b, m)
             self.store.metrics.rows_coalesced += n - len(gslots)
 
         def gather() -> BulkAcquireResult:
@@ -1061,18 +1087,23 @@ class _PackedLaunchMixin:
                               with_remaining: bool = True) -> BulkAcquireResult:
         counts_np = np.asarray(counts, np.int64)
         gather = self._bulk_plan(keys, counts_np, with_remaining)
-        return self._grant_probes(gather(), counts_np)
+        with annotate("drl/store.readback"):
+            res = gather()
+        return self._grant_probes(res, counts_np)
 
     async def acquire_many(self, keys: Sequence[str],
                            counts: Sequence[int], *,
                            with_remaining: bool = True) -> BulkAcquireResult:
         counts_np = np.asarray(counts, np.int64)
+        # One store_host sample covers both store-lock sections when the
+        # grouped plan declines.
+        t0 = time.perf_counter()
         gather = self._bulk_plan(keys, counts_np, with_remaining)
-        loop = asyncio.get_running_loop()
+        self.store.metrics.store_host.record(time.perf_counter() - t0)
         # ONE await resolves the whole call; the readback runs off-loop so
         # the event loop keeps serving (and other bulk calls' dispatches
         # overlap this one's transfer).
-        res = await loop.run_in_executor(None, gather)
+        res = await self._readback(gather)
         return self._grant_probes(res, counts_np)
 
 
@@ -1135,8 +1166,10 @@ class _DeviceTable(_PackedLaunchMixin):
     # -- slot management ---------------------------------------------------
     def resolve_slots(self, keys: list[str]) -> np.ndarray:
         """Batch key→slot resolution (the host hot path — one native call)."""
-        slots = _resolve_with_reclaim(self.dir, keys, self._sweep, self._grow,
-                                      min_free=self.n_slots // 16)
+        with annotate("drl/store.resolve"):
+            slots = _resolve_with_reclaim(self.dir, keys, self._sweep,
+                                          self._grow,
+                                          min_free=self.n_slots // 16)
         if self.dirty_slots is not None:
             self.dirty_slots.update(slots.tolist())
         self._maybe_pregrow()
@@ -1401,8 +1434,10 @@ class _DeviceWindowTable(_PackedLaunchMixin):
             self._warm_grouped()
 
     def resolve_slots(self, keys: list[str]) -> np.ndarray:
-        slots = _resolve_with_reclaim(self.dir, keys, self._sweep, self._grow,
-                                      min_free=self.n_slots // 16)
+        with annotate("drl/store.resolve"):
+            slots = _resolve_with_reclaim(self.dir, keys, self._sweep,
+                                          self._grow,
+                                          min_free=self.n_slots // 16)
         if self.dirty_slots is not None:
             self.dirty_slots.update(slots.tolist())
         self._maybe_pregrow()

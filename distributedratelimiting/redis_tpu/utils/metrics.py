@@ -279,6 +279,18 @@ class StoreMetrics:
     # not per request). serving p99 ≈ queue + flush + reply, each its own
     # scrapeable histogram instead of a bench-time inference.
     queue_latency: LatencyHistogram = field(default_factory=LatencyHistogram)
+    # The two host stages of a store call on the event loop (the
+    # packed-launch tables, runtime/store.py; blocking calls are not
+    # sampled), one sample per call: ``store_host`` is the store-lock
+    # section (directory resolve, operand packing, the jitted launch
+    # calls); ``store_readback`` runs from the executor submit to the
+    # host arrays in hand (a thread's wait, the device finishing, the
+    # transfer, the unpack). Recorded on the loop thread only. Exposed
+    # as stages of the drl_stage_latency_seconds family, not in
+    # snapshot().
+    store_host: LatencyHistogram = field(default_factory=LatencyHistogram)
+    store_readback: LatencyHistogram = field(
+        default_factory=LatencyHistogram)
     # Optional FlightRecorder (utils/flight_recorder.py) fed one frame per
     # flush by the store's flush observer; attached by the serving layer,
     # excluded from snapshot() (not a number).

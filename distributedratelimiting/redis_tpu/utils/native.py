@@ -342,6 +342,16 @@ def _bind_frontend(lib: ctypes.CDLL) -> ctypes.CDLL:
     except AttributeError:  # stale binary without the stage-hist ABI
         lib.has_stage_hist = False
     try:
+        # Where the Python-side fe_handoff stage starts: the current
+        # batch's cut / bulk job's arrival, on CLOCK_MONOTONIC.
+        lib.fe_batch_t_cut_ns.argtypes = [c.c_void_p]
+        lib.fe_batch_t_cut_ns.restype = c.c_ulonglong
+        lib.fe_bulk_t_ns.argtypes = [c.c_void_p]
+        lib.fe_bulk_t_ns.restype = c.c_ulonglong
+        lib.has_stage_stamps = True
+    except AttributeError:  # stale binary without the stamp accessors
+        lib.has_stage_stamps = False
+    try:
         lib.fe_batch_traced_n.argtypes = [c.c_void_p]
         lib.fe_batch_traced_n.restype = c.c_int
         lib.fe_batch_traces.argtypes = [c.c_void_p, c.POINTER(c.c_uint64),

@@ -38,12 +38,20 @@ Sampling model (the <3% serving-overhead contract, audited by the
 The default (tracer disabled, no profiling factory) path is
 allocation-free: ``span``/``start_span`` return a shared no-op context
 manager, so serving-path cost is one-or-two ``if``\\ s.
+
+3. Program spans on the device profiler's clock: :func:`annotate` and
+   every :meth:`Profiler.span` open a ``jax.profiler.TraceAnnotation``
+   named ``drl/<name>`` whenever a ``jax.profiler`` trace is being
+   collected, with or without a session factory — one jaxlib flag read
+   otherwise. The served path's spans are listed in
+   docs/OPERATIONS.md §5.
 """
 
 from __future__ import annotations
 
 import json
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -63,8 +71,7 @@ __all__ = [
     "current_context",
     "current_span",
     "mark",
-    "start_device_trace",
-    "stop_device_trace",
+    "annotate",
 ]
 
 
@@ -572,9 +579,10 @@ class Profiler:
 
     def span(self, command: str, rows: int = 1, *, annotate: bool = True,
              enabled: bool = True):
-        """Context manager timing one dispatch. No-op (shared, allocation
-        free) unless a session factory is registered or an ambient trace
-        is active.
+        """Context manager timing one dispatch. Without a session factory
+        or an ambient trace it is only the ``drl/<command>`` annotation
+        (:func:`annotate`): the shared no-op unless a ``jax.profiler``
+        trace is being collected.
 
         ``annotate=False`` skips the ``jax.profiler.TraceAnnotation``: trace
         annotations must nest strictly per thread, so spans that wrap
@@ -587,7 +595,7 @@ class Profiler:
             return _NULL_SPAN
         traced = _GLOBAL_TRACER.enabled and _CURRENT.get() is not None
         if self.session_factory is None and not traced:
-            return _NULL_SPAN
+            return _annotate(f"drl/{command}") if annotate else _NULL_SPAN
         return self._timed_span(command, rows, annotate, traced)
 
     @contextmanager
@@ -598,17 +606,16 @@ class Profiler:
                                            attrs={"rows": rows})
                  if traced else _NULL_SPAN)
         start = time.perf_counter()
-        if annotate:
-            annotation = _trace_annotation()(f"drl/{command}")
-            annotation.__enter__()
+        annotation = (_annotate(f"drl/{command}") if annotate
+                      else _NULL_SPAN)
+        annotation.__enter__()
         try:
             yield
         except BaseException:
             tspan.set_status("error")
             raise
         finally:
-            if annotate:
-                annotation.__exit__(None, None, None)
+            annotation.__exit__(None, None, None)
             tspan.end()
             if session is not None:
                 session.record(ProfiledCommand(
@@ -624,6 +631,25 @@ class Profiler:
 _TRACE_ANNOTATION = None
 
 
+def annotate(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` while a profiler
+    trace is being collected, else the shared no-op span. Costs one
+    jaxlib flag read when no trace runs, and nothing in a process that
+    never imported jax (no trace can run there). Annotations nest per
+    thread: wrap synchronous sections only, never an ``await``."""
+    cls = _TRACE_ANNOTATION
+    if cls is None:
+        if "jax" not in sys.modules:
+            return _NULL_SPAN
+        cls = _trace_annotation()
+    return cls(name) if cls.is_enabled() else _NULL_SPAN
+
+
+#: Module-internal alias: ``Profiler.span``'s ``annotate`` flag shadows
+#: the function's name inside it.
+_annotate = annotate
+
+
 def _trace_annotation():
     global _TRACE_ANNOTATION
     if _TRACE_ANNOTATION is None:
@@ -631,19 +657,3 @@ def _trace_annotation():
 
         _TRACE_ANNOTATION = TraceAnnotation
     return _TRACE_ANNOTATION
-
-
-def start_device_trace(logdir: str) -> None:
-    """Begin a device trace (XProf/Perfetto) covering subsequent kernel
-    launches; host-side :meth:`Profiler.span` annotations appear inline.
-    The TPU analogue of attaching a wire-level profiler to the Redis
-    connection."""
-    import jax
-
-    jax.profiler.start_trace(logdir)
-
-
-def stop_device_trace() -> None:
-    import jax
-
-    jax.profiler.stop_trace()

@@ -3236,6 +3236,17 @@ int fe_batch_traced_n(void* h) {
   return n;
 }
 
+// The current batch's cut stamp (now_ns: CLOCK_MONOTONIC, the clock
+// Python's time.perf_counter reads): where the pump -> loop hand-off
+// stage (Python's fe_handoff) starts. Same call window as
+// fe_batch_copy; 0 when there is no current batch.
+unsigned long long fe_batch_t_cut_ns(void* h) {
+  Shard* sh = shard_of(h);
+  std::lock_guard<FeMutex> lk(sh->mu);
+  auto it = sh->inflight.find(sh->cur_batch_id);
+  return it == sh->inflight.end() ? 0ull : it->second.t_flush_ns;
+}
+
 // Copy the current batch's trace contexts as parallel arrays (zeros /
 // flag bit 0 clear for untraced rows). Same contract as fe_batch_copy:
 // call between fe_wait returning 1 and fe_complete/fe_fail.
@@ -3889,6 +3900,16 @@ void fe_bulk_meta(void* h, unsigned long long* u, double* f) {
   u[10] = j.tr_flags;
   f[0] = j.a;
   f[1] = j.b;
+}
+
+// The current bulk job's arrival stamp (now_ns, as fe_batch_t_cut_ns):
+// where its hand-off to the loop starts. Same call window as
+// fe_bulk_meta; 0 when there is no current job.
+unsigned long long fe_bulk_t_ns(void* h) {
+  Shard* sh = shard_of(h);
+  std::lock_guard<FeMutex> lk(sh->mu);
+  auto it = sh->bulk_inflight.find(sh->cur_bulk_id);
+  return it == sh->bulk_inflight.end() ? 0ull : it->second.t_ns;
 }
 
 // ptrs[4]: key blob, offsets (i64[n+1]), counts (i64[n]), residue

@@ -628,7 +628,7 @@ def test_native_server_metrics_and_stage_decomposition():
                 await store.acquire(f"key{i % 4}", 1, 1e6, 1e6)
             text = await store.metrics()
             assert "drl_native_frontend 1" in text
-            for stage in ("native_queue", "native_exec"):
+            for stage in ("native_queue", "native_exec", "fe_handoff"):
                 assert (f'drl_stage_latency_seconds_bucket{{stage='
                         f'"{stage}"') in text, stage
             assert 'drl_hot_key_count{key="key0"}' in text
@@ -636,6 +636,13 @@ def test_native_server_metrics_and_stage_decomposition():
             st = stats["stages"]
             assert st["native_queue"]["samples"] > 0
             assert st["native_exec"]["samples"] > 0
+            # The pump -> loop hand-off: one sample per batch the C side
+            # cut (one request in flight at a time: one row each).
+            batches = srv._native.counts()[2]
+            assert st["fe_handoff"]["samples"] == batches == 150
+            assert st["native_exec"]["samples"] == batches
+            assert (srv._native.handoff_latency.sum_s
+                    <= srv._native.stage_histograms()["native_exec"].sum_s)
             # serving covers queue + exec: its p99 can't be below either
             # stage's p50 by construction (same windows, same samples).
             assert stats["serving_p99_ms"] >= st["native_queue"]["p50_ms"]
@@ -649,9 +656,61 @@ def test_native_server_metrics_and_stage_decomposition():
             await store.stats(reset=True)
             stats2 = await store.stats()
             assert "native_queue" not in stats2.get("stages", {})
+            assert "fe_handoff" not in stats2.get("stages", {})
         finally:
             await store.aclose()
             await srv.aclose()
+
+    run(body())
+
+
+@native_only
+@pytest.mark.jax_backend
+def test_native_server_store_stages_one_sample_per_call():
+    """Behind the native front end a device store splits native_exec:
+    ``fe_handoff`` takes one sample per batch or bulk job, and the store's
+    ``store_host`` / ``store_readback`` one per store call; the window
+    reset zeroes all three with the C stages."""
+    async def body():
+        dev = DeviceBucketStore(n_slots=1024, counter_slots=64,
+                                max_batch=64)
+        srv = BucketStoreServer(dev, native_frontend=True)
+        await srv.start()
+        store = RemoteBucketStore(address=(srv.host, srv.port),
+                                  coalesce_requests=False)
+        keys = [f"bulk{i}" for i in range(64)]
+        try:
+            # Compile both shapes before the window.
+            await store.acquire("warm", 1, 100.0, 10.0)
+            await store.acquire_many(keys, [1] * 64, 100.0, 10.0)
+            await store.stats(reset=True)
+            b0 = srv._native.counts()[2]
+            for i in range(12):
+                await store.acquire(f"k{i}", 1, 100.0, 10.0)
+            for _ in range(5):
+                await store.acquire_many(keys, [1] * 64, 100.0, 10.0)
+            batches = srv._native.counts()[2] - b0
+            assert batches == 12
+            st = (await store.stats())["stages"]
+            assert st["fe_handoff"]["samples"] == batches + 5
+            assert st["store_host"]["samples"] == batches + 5
+            assert st["store_readback"]["samples"] == batches + 5
+            hists = srv._native.stage_histograms()
+            assert hists["store_host"] is dev.metrics.store_host
+            assert dev.metrics.store_host.sum_s > 0.0
+            assert dev.metrics.store_readback.sum_s > 0.0
+            text = await store.metrics()
+            for stage in ("fe_handoff", "store_host", "store_readback"):
+                assert (f'drl_stage_latency_seconds_count{{stage='
+                        f'"{stage}"}} {batches + 5}') in text, stage
+            srv._native.reset_latency()
+            hists = srv._native.stage_histograms()
+            assert all(hists[s].total == 0 for s in
+                       ("fe_handoff", "store_host", "store_readback"))
+        finally:
+            await store.aclose()
+            await srv.aclose()
+            await dev.aclose()
 
     run(body())
 

@@ -301,6 +301,122 @@ class TestTracer:
         p = Profiler(None)
         assert p.span("anything") is tracing._NULL_SPAN
 
+    def test_annotate_is_null_outside_a_profiler_trace(self):
+        assert tracing.annotate("drl/store.resolve") is tracing._NULL_SPAN
+        with tracing.annotate("drl/store.resolve"):
+            pass
+
+
+# -- program spans on the device profiler's clock -----------------------------
+
+def _host_span_lines(trace_dir):
+    """``[[(name, start_ns, end_ns), ...] per host thread]`` of the
+    ``drl/`` events in the newest trace under ``trace_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                  for e in line.events if e.name.startswith("drl/")]
+            if ev:
+                lines.append(ev)
+    return lines
+
+
+class TestProgramSpans:
+    @pytest.mark.jax_backend
+    def test_profiler_span_annotates_inside_a_trace(self, tmp_path):
+        import jax
+
+        p = Profiler(None)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert p.span("x", annotate=False) is tracing._NULL_SPAN
+            span = p.span("acquire_many", 8)
+            assert span is not tracing._NULL_SPAN
+            with span:
+                with tracing.annotate("drl/store.resolve"):
+                    pass
+        finally:
+            jax.profiler.stop_trace()
+        (line,) = _host_span_lines(str(tmp_path))
+        names = [name for name, _, _ in line]
+        assert sorted(names) == ["drl/acquire_many", "drl/store.resolve"]
+        assert p.span("acquire_many") is tracing._NULL_SPAN
+
+    @pytest.mark.jax_backend
+    def test_served_path_spans_nest_per_thread(self, tmp_path):
+        """A real profiler trace of a native-front-end server over a CPU
+        device store holds every served-path span: resolve and dispatch
+        inside the store-lock span on the loop thread, the readback on
+        an executor thread, the pump's copy on its own."""
+        import jax
+
+        from distributedratelimiting.redis_tpu.utils.native import (
+            load_frontend_lib,
+        )
+
+        if load_frontend_lib() is None:
+            pytest.skip("native front-end library unavailable")
+
+        async def main():
+            dev = DeviceBucketStore(n_slots=1024, counter_slots=64,
+                                    clock=ManualClock(), max_batch=64)
+            async with BucketStoreServer(dev, native_frontend=True) as srv:
+                store = RemoteBucketStore(address=(srv.host, srv.port),
+                                          coalesce_requests=False)
+                keys = [f"k{i}" for i in range(64)]
+                try:
+                    await store.acquire("warm", 1, 100.0, 10.0)
+                    await store.acquire_many(keys, [1] * 64, 100.0, 10.0)
+                    jax.profiler.start_trace(str(tmp_path))
+                    try:
+                        for i in range(4):
+                            await store.acquire(f"a{i}", 1, 100.0, 10.0)
+                        for _ in range(3):
+                            await store.acquire_many(keys, [1] * 64,
+                                                     100.0, 10.0)
+                    finally:
+                        await asyncio.to_thread(jax.profiler.stop_trace)
+                finally:
+                    await store.aclose()
+            await dev.aclose()
+
+        run(main())
+        lines = _host_span_lines(str(tmp_path))
+        names = {name for line in lines for name, _, _ in line}
+        assert names >= {"drl/fe.pump", "drl/acquire_many",
+                         "drl/acquire_many_grouped", "drl/store.resolve",
+                         "drl/store.dispatch", "drl/store.readback",
+                         "drl/fe.complete"}
+        (loop,) = [line for line in lines
+                   if any(n == "drl/acquire_many" for n, _, _ in line)]
+        calls = [(a, b) for n, a, b in loop if n == "drl/acquire_many"]
+        assert len(calls) == 7       # 4 scalar batches + 3 bulk jobs
+
+        def inside(name):
+            return [sum(1 for n, a, b in loop
+                        if n == name and c0 <= a and b <= c1)
+                    for c0, c1 in calls]
+
+        assert inside("drl/store.dispatch") == [1] * 7
+        assert inside("drl/store.resolve") == [1] * 7
+        grouped = [(a, b) for n, a, b in loop
+                   if n == "drl/acquire_many_grouped"]
+        assert len(grouped) == 7     # the grouped plan declines first
+        for name in ("drl/store.readback", "drl/fe.pump"):
+            assert not any(n == name for n, _, _ in loop)
+            assert sum(n == name for line in lines
+                       for n, _, _ in line) == 7
+
 
 # -- end-to-end: wire-propagated span trees ----------------------------------
 
