@@ -317,6 +317,28 @@ def sliding_window_acquire(prev_count, curr_count, window_idx, exists, now,
     return prev_new, curr_new, idx_new, granted
 
 
+def segment_starts(sorted_keys):
+    """Bool mask of the first row of each run of equal sorted keys."""
+    return jnp.concatenate([jnp.ones((1,), bool),
+                            sorted_keys[1:] != sorted_keys[:-1]])
+
+
+def segment_cumsum(seg_start, values):
+    """Inclusive per-segment running sum of ``values`` (in segment order).
+
+    Sums reset at each segment boundary, so accumulation (and float32
+    rounding) stays per-key — a whole-batch cumsum would lose integer
+    precision past 2^24 total demand and could over-admit duplicates.
+    """
+    def seg_combine(a, b):
+        a_flag, a_val = a
+        b_flag, b_val = b
+        return a_flag | b_flag, jnp.where(b_flag, b_val, a_val + b_val)
+
+    _, inc = jax.lax.associative_scan(seg_combine, (seg_start, values))
+    return inc
+
+
 def duplicate_prefix(slots, counts, valid):
     """Per-request prefix of earlier same-slot demand within one batch.
 
@@ -345,19 +367,6 @@ def duplicate_prefix(slots, counts, valid):
     # "earlier same-slot demand" sum.
     order = jnp.argsort(slots, stable=True)
     c_sorted = counts_f[order]
-    s_sorted = slots[order]
-    seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), s_sorted[1:] != s_sorted[:-1]]
-    )
-    # Segmented inclusive scan: sums reset at each segment boundary, so
-    # accumulation (and float32 rounding) stays per-key — a whole-batch
-    # cumsum would lose integer precision past 2^24 total demand and could
-    # over-admit duplicates.
-    def seg_combine(a, b):
-        a_flag, a_val = a
-        b_flag, b_val = b
-        return a_flag | b_flag, jnp.where(b_flag, b_val, a_val + b_val)
-
-    _, inc = jax.lax.associative_scan(seg_combine, (seg_start, c_sorted))
-    prefix_sorted = inc - c_sorted
+    prefix_sorted = (segment_cumsum(segment_starts(slots[order]), c_sorted)
+                     - c_sorted)
     return jnp.zeros_like(counts_f).at[order].set(prefix_sorted)

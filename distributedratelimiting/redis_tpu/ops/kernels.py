@@ -14,9 +14,10 @@ design (TPU-first, SURVEY.md §7):
 - Atomicity (invariant 3) holds at batch granularity: XLA executes the
   whole gather → decide → scatter program as one serialized step over the
   state arrays, exactly as Redis serialized Lua scripts. Duplicate keys
-  within one batch are serialized conservatively via
-  :func:`~.bucket_math.duplicate_prefix` (never over-admit) — a sort-based
-  O(B log B) pass cheap enough to run unconditionally.
+  within one batch are serialized conservatively by the rule of
+  :func:`~.bucket_math.duplicate_prefix` (never over-admit): the token
+  bucket kernels sort each launch's rows by slot once, decide in that
+  order and write each touched slot back once.
 
 State layout is structure-of-arrays in HBM — ``tokens: f32[N]``,
 ``last_ts: i32[N]``, ``exists: bool[N]`` — 9 bytes/key, so 10M keys ≈ 90 MB,
@@ -150,6 +151,91 @@ def _scatter_slots(slots, valid, size):
     return jnp.where(valid, slots, size)
 
 
+#: A scatter whose indices are unique and sorted streams the whole table
+#: once, whatever its row count (~12 ps a slot on a TPU v5e: 50, 200 and
+#: 800 us at 2^22, 2^24 and 2^26 slots); an unsorted one pays per update
+#: row, real or dropped (~92 ns a row at each of those sizes). They cross
+#: near 7,900 slots a row, so the write-back sorts its indices when the
+#: table holds fewer than this many slots per row of the launch. At 2^20
+#: slots the two forms cost the same (PERF.md §5).
+SORTED_WRITE_SLOTS_PER_ROW = 8192
+
+
+def _write_back(state: BucketState, keys, seg_start, refilled, consumed,
+               any_fresh, now):
+    """Land one launch's decisions in the table, each touched slot ONCE:
+    ``tokens = refilled − Σ consumed`` over the slot's rows in the launch,
+    ``last_ts = now``, ``exists = True``.
+
+    The rows come in slot order (:func:`_decide_in_slot_order`): ``keys``
+    are their slots, invalid rows keyed ``size`` (past the table) and last,
+    ``seg_start`` marks each run of one slot. The last row of a valid run
+    carries the slot's write; every other row, padding included, goes to
+    an index of its own past the table and is dropped. The indices are
+    unique, so XLA issues the scatters without ordering same-address
+    updates. Same-slot rows share one refilled value (same gathered state,
+    same ``now``), and with integer counts and balances below 2^24 both
+    ``Σ consumed`` and the subtraction are exact: the result equals the
+    former four scatters (set refilled, add −consumed per row, set
+    ``last_ts``, set ``exists``) bit for bit. ``exists`` is written only
+    when ``any_fresh`` (some row found its slot empty); elsewhere every
+    touched slot already holds ``True``.
+    """
+    size = state.tokens.shape[0]
+    b = keys.shape[0]
+    if size + b > jnp.iinfo(jnp.int32).max:
+        raise ValueError(f"table of {size} slots leaves no int32 index "
+                         f"past its end for {b} rows")
+    seg_end = jnp.concatenate([seg_start[1:], jnp.ones((1,), bool)])
+    vals = refilled - bm.segment_cumsum(seg_start, consumed)
+    idx = jnp.where(seg_end & (keys < size), keys,
+                    size + jnp.arange(b, dtype=jnp.int32))
+    in_order = size < SORTED_WRITE_SLOTS_PER_ROW * b
+    if in_order:
+        idx, vals = jax.lax.sort((idx, vals), num_keys=1)
+
+    def put(arr, v, in_order=in_order):
+        return arr.at[idx].set(v, mode="drop", unique_indices=True,
+                               indices_are_sorted=in_order)
+
+    tokens = put(state.tokens, vals)
+    last_ts = put(state.last_ts, jnp.asarray(now, jnp.int32))
+    # The bool table's write takes the unsorted form: its sorted form
+    # costs seconds of TPU compile per kernel, for a write that only new
+    # keys need.
+    exists = jax.lax.cond(any_fresh, lambda e: put(e, True, False),
+                          lambda e: e, state.exists)
+    return BucketState(tokens, last_ts, exists)
+
+
+def _decide_in_slot_order(state: BucketState, slots, valid, now, capacity,
+                          fill_rate_per_tick, rows, decide):
+    """One decision launch, worked in slot order.
+
+    Sorts the rows by slot (stable: same-slot rows keep request order;
+    invalid rows sort last), carrying ``rows`` along as sort operands,
+    gathers each row's slot state and calls ``decide(refilled, valid,
+    seg_start, *rows)``, which returns ``(out_a, out_b, consumed)`` per
+    row. The state lands through :func:`_write_back`; the two outputs go
+    back to request order through a second sort."""
+    size = state.tokens.shape[0]
+    iota = jnp.arange(slots.shape[0], dtype=jnp.int32)
+    keys, order, *rows = jax.lax.sort(
+        (jnp.where(valid, slots, size), iota, *rows), num_keys=1,
+        is_stable=True)
+    valid = keys < size
+    seg_start = bm.segment_starts(keys)
+    gs = _gather_slots(keys, valid)
+    ex_old = state.exists[gs]
+    refilled = bm.refill_or_init(state.tokens[gs], state.last_ts[gs], ex_old,
+                                 now, capacity, fill_rate_per_tick)
+    out_a, out_b, consumed = decide(refilled, valid, seg_start, *rows)
+    state = _write_back(state, keys, seg_start, refilled, consumed,
+                       jnp.any(valid & ~ex_old), now)
+    _, out_a, out_b = jax.lax.sort((order, out_a, out_b), num_keys=1)
+    return state, out_a, out_b
+
+
 def acquire_core(state: BucketState, slots, counts, valid, now, capacity,
                  fill_rate_per_tick, *, handle_duplicates: bool = True,
                  prefix=None):
@@ -160,37 +246,26 @@ def acquire_core(state: BucketState, slots, counts, valid, now, capacity,
     computation when the caller already knows it (the host batcher computes
     it exactly during batch assembly)."""
     valid = _valid_slots(slots, valid, state.tokens.shape[0])
-    gs = _gather_slots(slots, valid)
-    t_old = state.tokens[gs]
-    ts_old = state.last_ts[gs]
-    ex_old = state.exists[gs]
+    rows = (jnp.asarray(counts, jnp.float32),)
+    if prefix is not None:
+        rows += (jnp.asarray(prefix, jnp.float32),)
 
-    counts_f = jnp.asarray(counts, jnp.float32)
-    refilled = bm.refill_or_init(t_old, ts_old, ex_old, now, capacity,
-                                 fill_rate_per_tick)
+    def decide(refilled, valid, seg_start, counts_f, prefix=None):
+        if prefix is None and handle_duplicates:
+            # Earlier same-slot demand, granted or not: the segmented
+            # exclusive sum of bucket_math.duplicate_prefix, in slot order.
+            demand = counts_f * valid
+            prefix = bm.segment_cumsum(seg_start, demand) - demand
+        elif prefix is None:
+            prefix = jnp.zeros_like(counts_f)
+        granted = valid & (refilled >= prefix + counts_f)
+        consumed = jnp.where(granted, counts_f, 0.0)
+        remaining = jnp.where(
+            valid, jnp.maximum(refilled - prefix - consumed, 0.0), 0.0)
+        return granted, remaining, consumed
 
-    if prefix is None and handle_duplicates:
-        prefix = bm.duplicate_prefix(slots, counts, valid)
-    elif prefix is None:
-        prefix = jnp.zeros_like(counts_f)
-    else:
-        prefix = jnp.asarray(prefix, jnp.float32)
-
-    granted = valid & (refilled >= prefix + counts_f)
-    consumed = jnp.where(granted, counts_f, 0.0)
-    remaining = jnp.where(valid, jnp.maximum(refilled - prefix - consumed, 0.0), 0.0)
-
-    ss = _scatter_slots(slots, valid, state.tokens.shape[0])
-    # Duplicates all write the identical refilled value (same now, same old
-    # state), then consumption accumulates via scatter-add.
-    new_tokens = state.tokens.at[ss].set(refilled, mode="drop")
-    new_tokens = new_tokens.at[ss].add(-consumed, mode="drop")
-    new_last_ts = state.last_ts.at[ss].set(
-        jnp.asarray(now, jnp.int32), mode="drop"
-    )
-    new_exists = state.exists.at[ss].set(True, mode="drop")
-
-    return BucketState(new_tokens, new_last_ts, new_exists), granted, remaining
+    return _decide_in_slot_order(state, slots, valid, now, capacity,
+                                 fill_rate_per_tick, rows, decide)
 
 
 @partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
@@ -275,39 +350,30 @@ def acquire_batch_packed_grouped(state: BucketState, packed, capacity,
     group, ``out[1] = post-consumption remaining`` (every member's view).
     """
     slots = packed[0]
-    counts = packed[1]
-    now = packed[2, 0]
-    prefix = jnp.asarray(packed[3], jnp.float32)
-    n_reqs = packed[4]
-    size = state.tokens.shape[0]
-    valid = _valid_slots(slots, slots >= 0, size)
-    gs = _gather_slots(slots, valid)
+    valid = _valid_slots(slots, slots >= 0, state.tokens.shape[0])
+    rows = (jnp.asarray(packed[1], jnp.float32),   # count per request
+            jnp.asarray(packed[3], jnp.float32),   # demand prefix
+            jnp.asarray(packed[4], jnp.float32))   # group size
 
-    refilled = bm.refill_or_init(state.tokens[gs], state.last_ts[gs],
-                                 state.exists[gs], now, capacity,
-                                 fill_rate_per_tick)
-    c = jnp.asarray(counts, jnp.float32)
-    n = jnp.asarray(n_reqs, jnp.float32)
-    avail = refilled - prefix
-    n_granted = jnp.where(
-        c > 0,
-        jnp.clip(jnp.floor(avail / jnp.maximum(c, 1.0)), 0.0, n),
-        # c == 0 probe group: granted iff the balance covers the prefix —
-        # the same `refilled >= prefix + 0` rule as the per-row kernel.
-        jnp.where(avail >= 0, n, 0.0),
-    )
-    n_granted = jnp.where(valid, n_granted, 0.0)
-    consumed = n_granted * c
-    remaining = jnp.where(valid, jnp.maximum(avail - consumed, 0.0), 0.0)
+    def decide(refilled, valid, seg_start, c, prefix, n):
+        avail = refilled - prefix
+        n_granted = jnp.where(
+            c > 0,
+            jnp.clip(jnp.floor(avail / jnp.maximum(c, 1.0)), 0.0, n),
+            # c == 0 probe group: granted iff the balance covers the
+            # prefix — the same `refilled >= prefix + 0` rule as the
+            # per-row kernel.
+            jnp.where(avail >= 0, n, 0.0),
+        )
+        n_granted = jnp.where(valid, n_granted, 0.0)
+        consumed = n_granted * c
+        remaining = jnp.where(valid, jnp.maximum(avail - consumed, 0.0), 0.0)
+        return n_granted, remaining, consumed
 
-    ss = _scatter_slots(slots, valid, size)
-    new_tokens = state.tokens.at[ss].set(refilled, mode="drop")
-    new_tokens = new_tokens.at[ss].add(-consumed, mode="drop")
-    new_last_ts = state.last_ts.at[ss].set(jnp.asarray(now, jnp.int32),
-                                           mode="drop")
-    new_exists = state.exists.at[ss].set(True, mode="drop")
-    out = jnp.stack([n_granted, remaining])
-    return BucketState(new_tokens, new_last_ts, new_exists), out
+    state, n_granted, remaining = _decide_in_slot_order(
+        state, slots, valid, packed[2, 0], capacity, fill_rate_per_tick,
+        rows, decide)
+    return state, jnp.stack([n_granted, remaining])
 
 
 @partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))

@@ -744,6 +744,24 @@ class _PackedLaunchMixin:
     #: checkpoint, only misreport the gauge.
     dirty_slots: "set[int] | None" = None
 
+    #: Operand widths below ``max_batch`` that a launch carrying fewer rows
+    #: is padded to instead: the smallest that holds its rows. A decision
+    #: launch's table scatters pay for every row, padding included
+    #: (PERF.md §5), so a one-row launch in a 16-row operand costs a
+    #: fraction of one padded to 4,096. Each width is one more compiled
+    #: variant of every served kernel, warmed with the table. Empty:
+    #: every launch is ``max_batch`` wide.
+    LAUNCH_WIDTHS: tuple[int, ...] = ()
+
+    def _launch_width(self, rows: int) -> int:
+        b = self.store.max_batch
+        return next((w for w in self.LAUNCH_WIDTHS if rows <= w < b), b)
+
+    def _launch_widths(self) -> list[int]:
+        """Every width a launch of this table can take (what to warm)."""
+        return sorted({self._launch_width(w) for w in self.LAUNCH_WIDTHS}
+                      | {self.store.max_batch})
+
     async def _flush(self, reqs: Sequence[_AcquireReq]) -> list[AcquireResult]:
         groups = (self._coalesce(reqs)
                   if self.store.coalesce_duplicates else None)
@@ -824,7 +842,7 @@ class _PackedLaunchMixin:
         with self.store.profiler.span("acquire_batch_grouped",
                                       len(groups)), self.store._lock:
             slots = self.resolve_slots([g[0] for g in groups])
-            b = self.store.max_batch
+            b = self._launch_width(len(groups))
             with annotate("drl/store.dispatch"):
                 now = self.store.now_ticks_checked()
                 packed = np.full((5, b), -1, np.int32)
@@ -921,18 +939,22 @@ class _PackedLaunchMixin:
                     k = 1
                     while k < rows and k < self._BULK_MAX_K:
                         k *= 2
-                    take = min(k * b, n - pos)
-                    s = np.full((k * b,), -1, np.int32)
+                    # The rare int32-count fallback keeps the full width:
+                    # its kernel compiles on first use, not with the table.
+                    w = (self._launch_width(n - pos)
+                         if rows == 1 and compact else b)
+                    take = min(k * w, n - pos)
+                    s = np.full((k * w,), -1, np.int32)
                     s[:take] = slots[pos:pos + take]
-                    c = np.zeros((k * b,), np.uint8 if compact else np.int32)
+                    c = np.zeros((k * w,), np.uint8 if compact else np.int32)
                     c[:take] = np.minimum(counts_np[pos:pos + take],
                                           2**31 - 1)
                     nows = np.full((k,), now, np.int32)
                     out = self._launch_scan_chunk(
-                        s.reshape(k, b), c.reshape(k, b), nows, compact,
+                        s.reshape(k, w), c.reshape(k, w), nows, compact,
                         with_remaining)
                     outs.append((out, take))
-                    self.store.metrics.record_launch(k * b, take)
+                    self.store.metrics.record_launch(k * w, take)
                     pos += take
         return outs
 
@@ -1029,7 +1051,8 @@ class _PackedLaunchMixin:
                 now = self.store.now_ticks_checked()
                 for pos in range(0, len(gslots), b):
                     m = min(b, len(gslots) - pos)
-                    packed = np.full((5, b), -1, np.int32)
+                    w = self._launch_width(m)
+                    packed = np.full((5, w), -1, np.int32)
                     packed[1] = 0
                     packed[3] = 0  # one group per slot per call ⇒ prefix 0
                     packed[4] = 0
@@ -1040,7 +1063,7 @@ class _PackedLaunchMixin:
                                                2**31 - 1)
                     out = self._launch_grouped(jnp.asarray(packed))
                     outs.append((out, m))
-                    self.store.metrics.record_launch(b, m)
+                    self.store.metrics.record_launch(w, m)
             self.store.metrics.rows_coalesced += n - len(gslots)
 
         def gather() -> BulkAcquireResult:
@@ -1136,6 +1159,8 @@ def _dirty_count(table) -> int:
 class _DeviceTable(_PackedLaunchMixin):
     """One homogeneous-config bucket table: device arrays + host directory."""
 
+    LAUNCH_WIDTHS = (16,)
+
     def __init__(self, store: "DeviceBucketStore", capacity: float,
                  fill_rate_per_sec: float, n_slots: int) -> None:
         self.store = store
@@ -1160,8 +1185,7 @@ class _DeviceTable(_PackedLaunchMixin):
             flush_observer=store._flush_observer,
         )
         self._pregrow_target = 0
-        if store.coalesce_duplicates:
-            self._warm_grouped()
+        self.state = self._warm_launches(self.state)
 
     # -- slot management ---------------------------------------------------
     def resolve_slots(self, keys: list[str]) -> np.ndarray:
@@ -1175,6 +1199,33 @@ class _DeviceTable(_PackedLaunchMixin):
         self._maybe_pregrow()
         return slots
 
+    def _warm_launches(self, state: K.BucketState) -> K.BucketState:
+        """Run every served decision kernel once at every launch width, on
+        all-padding operands (state values untouched): the flush's per-row
+        and grouped kernels, and a one-chunk bulk call with and without
+        remainders. A shape first met mid-serving would land a ~1 s TPU
+        compile inside the store lock, at whatever moment a flush or bulk
+        call first outgrew the narrow width."""
+        cap, rate = self.cap_dev, self.rate_dev
+        for w in self._launch_widths():
+            packed = np.full((5, w), -1, np.int32)
+            packed[1:] = 0
+            state, out = K.acquire_batch_packed(
+                state, jnp.asarray(packed[:4]), cap, rate)
+            if self.store.coalesce_duplicates:
+                state, out = K.acquire_batch_packed_grouped(
+                    state, jnp.asarray(packed), cap, rate)
+            fused = jnp.asarray(K.pack_compact5(
+                packed[:1], np.zeros((1, w), np.uint8)))
+            nows = jnp.zeros((1,), jnp.int32)
+            state, out = K.acquire_scan_fused_packed(state, fused, nows,
+                                                     cap, rate)
+            if w % 8 == 0:
+                state, out = K.acquire_scan_fused_bits(state, fused, nows,
+                                                       cap, rate)
+        jax.block_until_ready(out)
+        return state
+
     def _warm_for_size(self, n_slots: int) -> None:
         """One dummy pass of every serving+sweep kernel at ``n_slots`` —
         populates the jit cache for the post-grow shapes. Includes the
@@ -1186,18 +1237,9 @@ class _DeviceTable(_PackedLaunchMixin):
         runs concurrently with the live table, so holding it would keep
         transient device memory at ~3× through the 75%-occupancy window."""
         b = self.store.max_batch
-        state = K.init_bucket_state(n_slots)
-        packed = np.full((4, b), -1, np.int32)
-        packed[1:] = 0
-        state, out = K.acquire_batch_packed(
-            state, jnp.asarray(packed), self.cap_dev, self.rate_dev)
+        state = self._warm_launches(K.init_bucket_state(n_slots))
         state, _ = K.sweep_expired(state, jnp.int32(0), self.cap_dev,
                                    self.rate_dev)
-        if self.store.coalesce_duplicates:
-            packed5 = np.full((5, b), -1, np.int32)
-            packed5[1:] = 0
-            state, out = K.acquire_batch_packed_grouped(
-                state, jnp.asarray(packed5), self.cap_dev, self.rate_dev)
         k = self._BULK_MAX_K
         s = np.full((k, b), -1, np.int32)
         nows = np.zeros((k,), np.int32)
@@ -1296,11 +1338,10 @@ class _DeviceTable(_PackedLaunchMixin):
         with self.store.profiler.span("acquire_batch", len(reqs)), \
                 self.store._lock:
             slots = self.resolve_slots([r.key for r in reqs])
-            # Fixed pad width ⇒ exactly ONE compiled kernel per table (the
-            # extra rows are masked padding and cost ~nothing next to launch
-            # overhead; a varying pad width would recompile per size — ~1 s
-            # per size on TPU, fatal for serving-path p99).
-            b = self.store.max_batch
+            # Fixed pad widths (LAUNCH_WIDTHS, max_batch), each compiled
+            # with the table, never one per batch length (~1 s per size
+            # on TPU, fatal for serving-path p99).
+            b = self._launch_width(len(reqs))
             now = self.store.now_ticks_checked()
             packed = _build_packed(reqs, slots, b, now)
             self.state, out = K.acquire_batch_packed(

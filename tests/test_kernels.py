@@ -6,8 +6,10 @@ every grant/state when batches are duplicate-free, and must never over-admit
 when they are not (conservative in-batch serialization).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from distributedratelimiting.redis_tpu.ops import bucket_math as bm
 from distributedratelimiting.redis_tpu.ops import kernels as K
@@ -511,3 +513,217 @@ class TestWindowAcquireScanCompact:
                                           np.asarray(g2))
         np.testing.assert_allclose(np.asarray(s1.curr_count),
                                    np.asarray(s2.curr_count), rtol=1e-6)
+
+
+# -- write-back: one unique write per slot vs the four-scatter form -------
+#
+# The oracle below is the decision launch as it stood before the write-back
+# landed each slot once: set ``refilled`` on every row's slot, scatter-add
+# ``-consumed`` per row, then set ``last_ts`` and ``exists``. The kernels
+# must leave the same table and return the same outputs, bit for bit.
+
+CAP, RATE = 5.0, 10.0 / TPS  # 10/1024 per tick: every refill exact in f32
+WIDTH = 64
+# A 64-slot table takes the write-back's sorted-index form, a 2^20-slot
+# one (over K.SORTED_WRITE_SLOTS_PER_ROW slots per row) the unsorted form.
+TABLES = [64, 1 << 20]
+
+
+def _oracle_core(state, slots, counts, valid, now, prefix=None):
+    size = state.tokens.shape[0]
+    valid = valid & (slots >= 0) & (slots < size)
+    gs = jnp.where(valid, slots, 0)
+    counts_f = jnp.asarray(counts, jnp.float32)
+    refilled = bm.refill_or_init(state.tokens[gs], state.last_ts[gs],
+                                 state.exists[gs], now, CAP, RATE)
+    if prefix is None:
+        prefix = bm.duplicate_prefix(slots, counts, valid)
+    prefix = jnp.asarray(prefix, jnp.float32)
+    granted = valid & (refilled >= prefix + counts_f)
+    consumed = jnp.where(granted, counts_f, 0.0)
+    remaining = jnp.where(valid, jnp.maximum(refilled - prefix - consumed,
+                                             0.0), 0.0)
+    return _four_scatters(state, slots, valid, refilled, consumed, now), \
+        granted, remaining
+
+
+def _four_scatters(state, slots, valid, refilled, consumed, now):
+    ss = jnp.where(valid, slots, state.tokens.shape[0])
+    tokens = state.tokens.at[ss].set(refilled, mode="drop")
+    tokens = tokens.at[ss].add(-consumed, mode="drop")
+    last_ts = state.last_ts.at[ss].set(jnp.asarray(now, jnp.int32),
+                                       mode="drop")
+    exists = state.exists.at[ss].set(True, mode="drop")
+    return K.BucketState(tokens, last_ts, exists)
+
+
+@jax.jit
+def _oracle_packed(state, packed):
+    st, g, r = _oracle_core(state, packed[0], packed[1], packed[0] >= 0,
+                            packed[2, 0], packed[3])
+    return st, jnp.stack([g.astype(jnp.float32), r])
+
+
+@jax.jit
+def _oracle_grouped(state, packed):
+    slots, now, n = packed[0], packed[2, 0], packed[4]
+    size = state.tokens.shape[0]
+    valid = (slots >= 0) & (slots < size)
+    gs = jnp.where(valid, slots, 0)
+    refilled = bm.refill_or_init(state.tokens[gs], state.last_ts[gs],
+                                 state.exists[gs], now, CAP, RATE)
+    c = packed[1].astype(jnp.float32)
+    avail = refilled - packed[3].astype(jnp.float32)
+    n_granted = jnp.where(
+        c > 0, jnp.clip(jnp.floor(avail / jnp.maximum(c, 1.0)), 0.0,
+                        n.astype(jnp.float32)),
+        jnp.where(avail >= 0, n.astype(jnp.float32), 0.0))
+    n_granted = jnp.where(valid, n_granted, 0.0)
+    consumed = n_granted * c
+    remaining = jnp.where(valid, jnp.maximum(avail - consumed, 0.0), 0.0)
+    st = _four_scatters(state, slots, valid, refilled, consumed, now)
+    return st, jnp.stack([n_granted, remaining])
+
+
+@jax.jit
+def _oracle_scan(state, slots_k, counts_k, nows_k):
+    def body(st, xs):
+        slots, counts, now = xs
+        st, g, r = _oracle_core(st, slots, counts.astype(jnp.int32),
+                                slots >= 0, now)
+        return st, jnp.stack([g.astype(jnp.float32), r])
+    return jax.lax.scan(body, state, (slots_k, counts_k, nows_k))
+
+
+def _seed_state(n):
+    """Slots 0-39 hold balances (whole, halves, quarters, empty) stamped at
+    assorted ticks; slots from 40 on were never touched (``exists``
+    false)."""
+    tokens = np.zeros(n, np.float32)
+    tokens[:40] = np.resize([5.0, 0.0, 2.5, 1.0, 3.75, 0.25, 4.0, 2.0], 40)
+    last_ts = np.zeros(n, np.int32)
+    last_ts[:40] = np.resize([900, 1000, 950, 1000, 700], 40)
+    exists = np.zeros(n, bool)
+    exists[:40] = True
+    return tokens, last_ts, exists
+
+
+def _state(arrays):
+    return K.BucketState(*(jnp.array(a, copy=True) for a in arrays))
+
+
+# Each scenario: launches of (now, [(slot, count), ...]).
+WRITE_BACK_SCENARIOS = {
+    "dup_equal_counts": [(1000, [(3, 1), (3, 1), (3, 1), (3, 1), (5, 2),
+                                 (5, 2), (6, 1)])],
+    "dup_mixed_counts": [(1000, [(3, 2), (7, 1), (3, 1), (3, 3), (7, 4),
+                                 (3, 1)])],
+    "denied_then_smaller": [(1000, [(2, 3), (2, 1), (12, 9), (12, 2),
+                                    (12, 1)])],
+    "zero_permit_probes": [(1000, [(9, 0), (9, 2), (9, 0), (1, 0), (1, 1),
+                                   (1, 0), (44, 0)])],
+    "new_keys": [(1000, [(41, 1), (42, 5), (43, 2), (43, 2), (43, 2),
+                         (45, 0), (46, 6)])],
+    "padding_only": [(1000, []), (1001, [])],
+    "relaunch_same_tick": [(1000, [(3, 2), (41, 3), (4, 1)]),
+                           (1000, [(3, 2), (41, 3), (4, 1), (3, 1)]),
+                           (1000, [(41, 1), (41, 1)])],
+}
+
+
+def _layout(rows, where):
+    """Row positions in the WIDTH-row operand: packed at the front, or
+    ending at the last row (every live-width cut is then the full one)."""
+    if where == "front":
+        return list(range(len(rows)))
+    return list(range(WIDTH - len(rows), WIDTH))
+
+
+def _packed_rows(now, rows, where):
+    packed = np.full((4, WIDTH), -1, np.int32)
+    packed[1:] = 0
+    packed[2] = now
+    demand: dict[int, int] = {}
+    for pos, (slot, count) in zip(_layout(rows, where), rows):
+        packed[:, pos] = (slot, count, now, demand.get(slot, 0))
+        demand[slot] = demand.get(slot, 0) + count
+    return packed
+
+
+def _grouped_rows(now, rows, where):
+    """The store's coalescing: one (slot, count, n) row per key when its
+    counts agree, else one row per request with its exact prefix."""
+    by_slot: dict[int, list[int]] = {}
+    for slot, count in rows:
+        by_slot.setdefault(slot, []).append(count)
+    groups = []
+    for slot, counts in by_slot.items():
+        if len(set(counts)) == 1:
+            groups.append((slot, counts[0], 0, len(counts)))
+        else:
+            pref = 0
+            for c in counts:
+                groups.append((slot, c, pref, 1))
+                pref += c
+    packed = np.full((5, WIDTH), -1, np.int32)
+    packed[1:] = 0
+    packed[2] = now
+    for pos, g in zip(_layout(groups, where), groups):
+        packed[[0, 1, 3, 4], pos] = g
+    return packed
+
+
+def _assert_same(new, ref):
+    """Bit-for-bit equality of states and outputs (floats compared as their
+    32-bit patterns, so -0.0 and 0.0 differ)."""
+    for a, b in zip(jax.tree_util.tree_leaves(new),
+                    jax.tree_util.tree_leaves(ref)):
+        a, b = np.asarray(a), np.asarray(b)
+        if a.dtype == np.float32:
+            a, b = a.view(np.uint32), b.view(np.uint32)
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("n", TABLES)
+@pytest.mark.parametrize("where", ["front", "tail"])
+@pytest.mark.parametrize("scenario", sorted(WRITE_BACK_SCENARIOS))
+class TestWriteBackMatchesFourScatter:
+    def test_acquire_batch_packed(self, scenario, where, n):
+        new, ref = _state(_seed_state(n)), _state(_seed_state(n))
+        for now, rows in WRITE_BACK_SCENARIOS[scenario]:
+            packed = jnp.asarray(_packed_rows(now, rows, where))
+            new, out = K.acquire_batch_packed(new, packed, jnp.float32(CAP),
+                                              jnp.float32(RATE))
+            ref, out_ref = _oracle_packed(ref, packed)
+            _assert_same((new, out), (ref, out_ref))
+
+    def test_acquire_batch_packed_grouped(self, scenario, where, n):
+        new, ref = _state(_seed_state(n)), _state(_seed_state(n))
+        for now, rows in WRITE_BACK_SCENARIOS[scenario]:
+            packed = jnp.asarray(_grouped_rows(now, rows, where))
+            new, out = K.acquire_batch_packed_grouped(
+                new, packed, jnp.float32(CAP), jnp.float32(RATE))
+            ref, out_ref = _oracle_grouped(ref, packed)
+            _assert_same((new, out), (ref, out_ref))
+
+    @pytest.mark.parametrize("bits", [False, True])
+    def test_acquire_scan_fused(self, scenario, where, n, bits):
+        """All of a scenario's launches in ONE scanned call (K launches)."""
+        launches = WRITE_BACK_SCENARIOS[scenario]
+        slots = np.full((len(launches), WIDTH), -1, np.int32)
+        counts = np.zeros((len(launches), WIDTH), np.uint8)
+        for k, (_, rows) in enumerate(launches):
+            for pos, (slot, count) in zip(_layout(rows, where), rows):
+                slots[k, pos], counts[k, pos] = slot, count
+        nows = np.array([now for now, _ in launches], np.int32)
+        fused = jnp.asarray(K.pack_compact5(slots, counts))
+        kernel = (K.acquire_scan_fused_bits if bits
+                  else K.acquire_scan_fused_packed)
+        new, out = kernel(_state(_seed_state(n)), fused, jnp.asarray(nows),
+                          jnp.float32(CAP), jnp.float32(RATE))
+        ref, out_ref = _oracle_scan(_state(_seed_state(n)), jnp.asarray(slots),
+                                    jnp.asarray(counts), jnp.asarray(nows))
+        if bits:
+            granted = np.asarray(out_ref)[:, 0] > 0.5
+            out_ref = np.packbits(granted, axis=-1, bitorder="little")
+        _assert_same((new, out), (ref, out_ref))

@@ -665,3 +665,78 @@ class TestFlushCoalescing:
             await off.aclose()
 
         run(main())
+
+
+class TestLaunchWidths:
+    """A bucket-table launch is padded to the smallest of its widths (16,
+    ``max_batch``) that holds its rows; the decisions do not depend on the
+    width, and every width is compiled with the table."""
+
+    @pytest.mark.parametrize("n, width", [(1, 16), (16, 16), (17, 4096),
+                                          (256, 4096), (257, 4096)])
+    def test_bulk_call_pads_to_the_smallest_width(self, clock, rng, n,
+                                                  width):
+        dev = device_store(clock, n_slots=1024)
+        ref = InProcessBucketStore(clock=clock)
+        keys = [f"k{i}" for i in rng.permutation(n)]
+        counts = [int(c) for c in rng.integers(0, 4, n)]
+        before = dev.metrics.rows_processed
+        for _ in range(3):  # spend, then spend what is left
+            bulk = dev.acquire_many_blocking(keys, counts, 5.0, 4.0)
+            seq = [ref.acquire_blocking(k, c, 5.0, 4.0)
+                   for k, c in zip(keys, counts)]
+            assert [bool(g) for g in bulk.granted] == [r.granted for r in seq]
+            np.testing.assert_allclose(
+                bulk.remaining, [r.remaining for r in seq], atol=1e-4)
+            clock.advance_seconds(0.25)
+        assert dev.metrics.rows_processed - before == 3 * width
+
+    def test_flushes_pad_to_16(self, clock):
+        """One-row flushes and a coalesced hot key (one grouped row)."""
+        dev = device_store(clock, max_delay_s=0.005)
+
+        async def main():
+            assert dev.acquire_blocking("a", 1, 5.0, 1.0).granted
+            assert dev.metrics.rows_processed == 16
+            results = await asyncio.gather(
+                *(dev.acquire("hot", 1, 3.0, 1.0) for _ in range(8)))
+            assert [r.granted for r in results] == [True] * 3 + [False] * 5
+            assert dev.metrics.rows_processed == 16 * dev.metrics.launches
+            await dev.aclose()
+
+        run(main())
+
+    def test_no_width_compiles_while_serving(self, clock):
+        """Once a table exists, flushes and bulk calls of 1, 17 and 257
+        rows, with and without remainders and with in-batch duplicates,
+        compile nothing: each width was compiled with the table."""
+        import jax
+
+        dev = device_store(clock, n_slots=2048, max_delay_s=0.005)
+        dev.acquire_blocking("first", 1, 5.0, 1.0)  # builds the table
+        compiles = []
+
+        def on_event(name, secs, **_):
+            if name == "/jax/core/compile/backend_compile_duration":
+                compiles.append(secs)
+
+        async def main():
+            for n in (1, 17, 257):
+                keys = [f"n{n}-{i}" for i in range(n)]
+                await asyncio.gather(*(dev.acquire(k, 1, 5.0, 1.0)
+                                       for k in keys))
+                await asyncio.gather(*(dev.acquire(keys[0], 1, 5.0, 1.0)
+                                       for _ in range(n)))
+                for with_remaining in (True, False):
+                    await dev.acquire_many(keys, [1] * n, 5.0, 1.0,
+                                           with_remaining=with_remaining)
+                await dev.acquire_many(keys[:1] * n, [1] * n, 5.0, 1.0)
+            await dev.aclose()
+
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            run(main())
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_event)
+        assert dev.metrics.launches > 12
+        assert compiles == []

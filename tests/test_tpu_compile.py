@@ -11,6 +11,7 @@ imports every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,6 +94,28 @@ def test_bulk_scan_at_smoke_size(one_chip, kernel):
         sds((32, 4096, 5), jnp.uint8, one_chip),
         sds((32,), jnp.int32, one_chip), *scalars(one_chip)).compile()
     hbm_fits(compiled)
+
+
+@pytest.mark.parametrize("n, width, in_order", [
+    (SMOKE_SLOTS, 16, False), (SMOKE_SLOTS, 4096, True),
+    (SMOKE_FP_SLOTS, 4096, False)])
+def test_write_back_stays_in_place(one_chip, n, width, in_order):
+    """The served kernel at the bucket table's launch widths, at the
+    benchmark's 2^24 slots and the fingerprint store's 2^26: three
+    scatters land in the table (tokens, last_ts, exists on a new key) and
+    nothing copies it. ``tokens`` and ``last_ts`` take sorted indices only
+    where the table holds under SORTED_WRITE_SLOTS_PER_ROW slots a row."""
+    compiled = K.acquire_scan_fused_packed.lower(
+        bucket_state(n, one_chip),
+        sds((1, width, 5), jnp.uint8, one_chip),
+        sds((1,), jnp.int32, one_chip), *scalars(one_chip)).compile()
+    table = rf"\[{n}\]\S* "
+    scatters = re.findall(table + r"scatter\(.*", compiled.as_text())
+    assert len(scatters) == 3
+    assert sum("indices_are_sorted=true" in s
+               for s in scatters) == (2 if in_order else 0)
+    assert not re.search(table + r"copy(-start)?\(", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
 
 
 @pytest.mark.parametrize("n", [N, SMOKE_FP_SLOTS])

@@ -384,6 +384,10 @@ class TestProgramSpans:
                         for _ in range(3):
                             await store.acquire_many(keys, [1] * 64,
                                                      100.0, 10.0)
+                        # The pump hands a frame to the loop from inside
+                        # its span, so the reply can arrive before the
+                        # span's end: let the last one close first.
+                        await asyncio.sleep(0.2)
                     finally:
                         await asyncio.to_thread(jax.profiler.stop_trace)
                 finally:
