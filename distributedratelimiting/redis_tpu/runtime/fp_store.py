@@ -18,10 +18,13 @@ decides it in the SAME launch. What this buys over the host directory:
 The trade (made explicit, not hidden): requests ship 8-byte fingerprints
 instead of packed slot ids, so per-decision transfer is larger than the
 packed24 host-directory path, while this store saves host CPU and
-memory (which side wins on the chip is not measured) (the SURVEY.md §7 "device-side
-hashing/eviction/TTL" regime). Fingerprint collisions (two keys sharing a
-bucket) occur with probability ≈ n²/2⁶⁵ — about 3·10⁻⁶ at 10M keys —
-versus never for the byte-comparing host directory; see
+memory (the SURVEY.md §7 "device-side hashing/eviction/TTL" regime). At
+10M uniformly drawn keys in 4,096-key bulk calls on one TPU v5e this store
+decides more per second than the host directory (benchmark cells
+``fp10m.bulk-uniform`` and ``tb10m.bulk-uniform``, PERF.md). Fingerprint
+collisions (two keys sharing a bucket) occur with probability ≈ n²/2⁶⁵ —
+about 3·10⁻⁶ at 10M keys — versus never for the byte-comparing host
+directory; see
 ``ops/fp_directory.py`` for the full disclosure.
 
 The keyed hot tiers — token buckets AND sliding/fixed windows (the two
@@ -35,8 +38,8 @@ tool there.
 
 from __future__ import annotations
 
-import asyncio
 import ctypes
+import time
 from typing import Sequence
 
 import jax
@@ -54,9 +57,11 @@ from distributedratelimiting.redis_tpu.runtime.store import (
     _grant_zero_probes,
     _pad_size,
     _rate_per_tick,
+    _readback,
     _shift_ts,
 )
 from distributedratelimiting.redis_tpu.utils.native import load_directory_lib
+from distributedratelimiting.redis_tpu.utils.tracing import annotate
 
 __all__ = ["FingerprintBucketStore", "fingerprints"]
 
@@ -194,7 +199,7 @@ class _FpTable:
                       valid: np.ndarray):
         """One fused resolve+acquire dispatch; returns device handles."""
         store = self.store
-        with store._lock:
+        with store._lock, annotate("drl/store.dispatch"):
             now = store.now_ticks_checked()
             out = self._call_batch(kpair, counts, valid, now)
             n_valid = int(valid.sum())
@@ -219,18 +224,22 @@ class _FpTable:
     async def _flush(self, reqs: Sequence[_AcquireReq]) -> list[AcquireResult]:
         n = len(reqs)
         b = _pad_size(n)
+        # One store_host sample: the hash and the store-lock section.
+        t0 = time.perf_counter()
         kpair = np.zeros((b, 2), np.uint32)
-        kpair[:n] = fingerprints([r.key for r in reqs])
+        with annotate("drl/store.hash"):
+            kpair[:n] = fingerprints([r.key for r in reqs])
         counts = np.zeros((b,), np.int32)
         counts[:n] = [min(r.count, 2**31 - 1) for r in reqs]
         valid = np.zeros((b,), bool)
         valid[:n] = True
         granted_d, remaining_d, resolved_d = self._launch_batch(
             kpair, counts, valid)
-        loop = asyncio.get_running_loop()
-        g, r, res = await loop.run_in_executor(
-            None, lambda: (np.asarray(granted_d), np.asarray(remaining_d),
-                           np.asarray(resolved_d)))
+        self.store.metrics.store_host.record(time.perf_counter() - t0)
+        g, r, res = await _readback(
+            self.store, lambda: (np.asarray(granted_d),
+                                 np.asarray(remaining_d),
+                                 np.asarray(resolved_d)))
         g, r, _ = self._postprocess(g, r, res, counts, n)
         return [AcquireResult(bool(g[i]), float(r[i])) for i in range(n)]
 
@@ -258,12 +267,14 @@ class _FpTable:
         (bit-planes on the verdict-only path), keeping the transfer count
         per call minimal (its share on the chip is not measured)."""
         n = len(keys)
-        fps = fingerprints(keys)  # KeyBlob-aware
+        with annotate("drl/store.hash"):
+            fps = fingerprints(keys)  # KeyBlob-aware
         b = self.store.max_batch
         outs = []
         store = self.store
         pos = 0
-        with store.profiler.span("acquire_many_fp", n), store._lock:
+        with store.profiler.span("acquire_many_fp", n), store._lock, \
+                annotate("drl/store.dispatch"):
             now = store.now_ticks_checked()
             if self.dirty_rows is not None:
                 self.dirty_rows += n
@@ -332,17 +343,21 @@ class _FpTable:
         counts_np = np.asarray(counts, np.int64)
         outs = self._bulk_dispatch(keys, counts_np,
                                    with_remaining=with_remaining)
-        return self._gather_bulk(outs, counts_np, with_remaining)
+        with annotate("drl/store.readback"):
+            return self._gather_bulk(outs, counts_np, with_remaining)
 
     async def acquire_many(self, keys: Sequence[str],
                            counts: Sequence[int], *,
                            with_remaining: bool = True) -> BulkAcquireResult:
         counts_np = np.asarray(counts, np.int64)
+        # One store_host sample: the hash and the store-lock section.
+        t0 = time.perf_counter()
         outs = self._bulk_dispatch(keys, counts_np,
                                    with_remaining=with_remaining)
-        loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(
-            None, lambda: self._gather_bulk(outs, counts_np, with_remaining))
+        self.store.metrics.store_host.record(time.perf_counter() - t0)
+        return await _readback(
+            self.store,
+            lambda: self._gather_bulk(outs, counts_np, with_remaining))
 
     def _debit_launch(self, keys: Sequence[str],
                       amounts: Sequence[float]):

@@ -660,6 +660,23 @@ def _pad_size(n: int, floor: int = 64) -> int:
     return size
 
 
+async def _readback(store, fetch: Callable[[], object]):
+    """Run ``fetch`` (device→host transfer and unpack) on the loop's
+    executor under the ``drl/store.readback`` span; records ``store``'s
+    ``store_readback`` stage from the submit to the host arrays in hand
+    on the executor thread (the loop's wake-up after it is not counted).
+    Shared by the host-directory tables and the fingerprint store."""
+    def job():
+        with annotate("drl/store.readback"):
+            out = fetch()
+        return out, time.perf_counter()
+
+    t0 = time.perf_counter()
+    out, t1 = await asyncio.get_running_loop().run_in_executor(None, job)
+    store.metrics.store_readback.record(t1 - t0)
+    return out
+
+
 def _duplicate_prefix_host(slots: np.ndarray, counts: np.ndarray) -> np.ndarray:
     """Exact per-request prefix of earlier same-slot demand, computed on the
     host in int64 (vectorized stable-sort + segmented cumsum, ~30µs at
@@ -772,7 +789,7 @@ class _PackedLaunchMixin:
         out = (self._launch(reqs) if groups is None
                else self._dispatch_grouped(groups))
         self.store.metrics.store_host.record(time.perf_counter() - t0)
-        out_np = await self._readback(lambda: np.asarray(out))
+        out_np = await _readback(self.store, lambda: np.asarray(out))
         if groups is None:
             return [
                 AcquireResult(bool(out_np[0, i] > 0.5), float(out_np[1, i]))
@@ -792,22 +809,6 @@ class _PackedLaunchMixin:
                     granted,
                     max(avail - j * count - (count if granted else 0), 0.0))
         return results  # type: ignore[return-value]
-
-    async def _readback(self, fetch: Callable[[], object]):
-        """Run ``fetch`` (device→host transfer and unpack) on the loop's
-        executor under the ``drl/store.readback`` span; records the
-        ``store_readback`` stage from the submit to the host arrays in
-        hand on the executor thread (the loop's wake-up after it is not
-        counted)."""
-        def job():
-            with annotate("drl/store.readback"):
-                out = fetch()
-            return out, time.perf_counter()
-
-        t0 = time.perf_counter()
-        out, t1 = await asyncio.get_running_loop().run_in_executor(None, job)
-        self.store.metrics.store_readback.record(t1 - t0)
-        return out
 
     @staticmethod
     def _coalesce(reqs: Sequence[_AcquireReq]):
@@ -1126,7 +1127,7 @@ class _PackedLaunchMixin:
         # ONE await resolves the whole call; the readback runs off-loop so
         # the event loop keeps serving (and other bulk calls' dispatches
         # overlap this one's transfer).
-        res = await self._readback(gather)
+        res = await _readback(self.store, gather)
         return self._grant_probes(res, counts_np)
 
 

@@ -658,3 +658,147 @@ class TestFingerprintStore:
             await store.aclose()
 
         run(main())
+
+
+def _blob(keys):
+    """The native bulk lane's key form (``wire.KeyBlob``) of ``keys``."""
+    from distributedratelimiting.redis_tpu.runtime.wire import KeyBlob
+
+    raw = [k.encode("utf-8", "surrogateescape") for k in keys]
+    offsets = np.zeros(len(raw) + 1, np.int64)
+    offsets[1:] = np.cumsum([len(r) for r in raw])
+    return KeyBlob(b"".join(raw), offsets)
+
+
+class TestServedStages:
+    """The served path's host stages, one sample per store call, as the
+    host-directory tables record them (runtime/store.py)."""
+
+    def test_bulk_call_records_one_sample_per_stage(self):
+        async def main():
+            store = FingerprintBucketStore(n_slots=1024, clock=ManualClock())
+            m = store.metrics
+            keys = [f"k{i}" for i in range(100)]
+            for i in range(3):
+                res = await store.acquire_many(
+                    _blob(keys) if i % 2 else keys, [1] * 100, 5.0, 1.0,
+                    with_remaining=bool(i % 2))
+                assert res.granted.all()
+                assert m.store_host.total == m.store_readback.total == i + 1
+            assert m.store_host.sum_s > 0.0 and m.store_readback.sum_s > 0.0
+            await store.aclose()
+
+        run(main())
+
+    def test_flush_records_one_sample_per_stage(self):
+        async def main():
+            store = FingerprintBucketStore(n_slots=256, clock=ManualClock())
+            m = store.metrics
+            for i in range(3):
+                assert (await store.acquire(f"k{i}", 1, 3.0, 1.0)).granted
+                assert m.store_host.total == m.store_readback.total == i + 1
+            assert m.flush_latency.total == 3
+            await store.aclose()
+
+        run(main())
+
+    def test_untraced_store_opens_no_span(self, monkeypatch):
+        """Without a profiler trace the served calls construct no trace
+        annotation: each span is the shared no-op after one flag read."""
+        from jax.profiler import TraceAnnotation
+
+        from distributedratelimiting.redis_tpu.utils import tracing
+
+        opened = []
+
+        class Recording(TraceAnnotation):
+            def __init__(self, name, **kw):
+                opened.append(name)
+                super().__init__(name, **kw)
+
+        monkeypatch.setattr(tracing, "_TRACE_ANNOTATION", Recording)
+
+        async def main():
+            store = FingerprintBucketStore(n_slots=1024, clock=ManualClock())
+            keys = [f"k{i}" for i in range(64)]
+            await store.acquire_many(_blob(keys), [1] * 64, 5.0, 1.0)
+            await store.acquire("one", 1, 5.0, 1.0)
+            assert store.metrics.store_host.total == 2
+            await store.aclose()
+
+        run(main())
+        assert opened == []
+
+
+class TestServedKeyForm:
+    """The key form the native bulk lane hands the store, and the key
+    space the benchmark's ``token-bucket-10m-fp`` configuration serves."""
+
+    def test_keyblob_fingerprints_match_list_and_python(self):
+        from distributedratelimiting.redis_tpu.runtime import fp_store
+
+        keys = ["a", "user:42", "ключ-🔑", "", "x" * 300,
+                "api:user00000000", "api:user09999999"]
+        want = fingerprints(keys)
+        np.testing.assert_array_equal(fingerprints(_blob(keys)), want)
+        for i, k in enumerate(keys):
+            h = fp_store._fp64_py(k)
+            assert (int(want[i, 0]), int(want[i, 1])) == (h & 0xFFFFFFFF,
+                                                          h >> 32)
+
+    def test_keyblob_bulk_decides_like_in_process_store(self):
+        async def main():
+            clock = ManualClock()
+            store = FingerprintBucketStore(n_slots=4096, clock=clock)
+            oracle = InProcessBucketStore(clock=clock)
+            rng = np.random.default_rng(11)
+            for _ in range(6):
+                idx = rng.choice(500, 256, replace=False)
+                keys = [f"api:user{i:08d}" for i in idx]
+                counts = rng.integers(0, 4, 256).tolist()
+                got = await store.acquire_many(_blob(keys), counts, 5.0, 2.0)
+                want = await oracle.acquire_many(keys, counts, 5.0, 2.0)
+                np.testing.assert_array_equal(got.granted, want.granted)
+                np.testing.assert_allclose(got.remaining, want.remaining,
+                                           atol=1e-4)
+                clock.advance_seconds(0.7)
+            assert store.metrics.fp_unresolved == 0
+            await store.aclose()
+            await oracle.aclose()
+
+        run(main())
+
+    def test_benchmark_key_space_fingerprints_are_distinct(self):
+        """Every key of ``token-bucket-10m-fp`` has its own 64-bit
+        fingerprint, so two keys never share a bucket there and the
+        store's answers are exactly the token bucket's: the precondition
+        that configuration states. FNV-1a-64 in numpy over the fixed-width
+        names, checked against ``_fp64_py`` and the native pass."""
+        import json
+        import pathlib
+
+        from distributedratelimiting.redis_tpu.runtime import fp_store
+
+        cfg = json.loads((pathlib.Path(__file__).resolve().parents[1]
+                          / "perfbench" / "configs"
+                          / "token-bucket-10m-fp.json").read_text())
+        spec = cfg["keys"]
+        n, digits = int(spec["count"]), int(spec["digits"])
+        prefix = spec["prefix"].encode()
+        idx = np.arange(n, dtype=np.uint64)
+        h = np.full(n, fp_store._FNV_OFFSET, np.uint64)
+        prime = np.uint64(fp_store._FNV_PRIME)
+        for byte in prefix:
+            h ^= np.uint64(byte)
+            h *= prime
+        for d in range(digits - 1, -1, -1):
+            h ^= (idx // np.uint64(10 ** d)) % np.uint64(10) + np.uint64(48)
+            h *= prime
+        h[h == 0] = np.uint64(fp_store._FNV_OFFSET)
+        sample = [0, 1, 12_345, n // 2, n - 1]
+        names = [f"{spec['prefix']}{i:0{digits}d}" for i in sample]
+        native = fingerprints(names)
+        for j, (i, name) in enumerate(zip(sample, names)):
+            assert int(h[i]) == fp_store._fp64_py(name)
+            assert int(h[i]) == int(native[j, 0]) | int(native[j, 1]) << 32
+        assert len(np.unique(h)) == n

@@ -138,6 +138,23 @@ def test_fp_bulk_scan_at_smoke_size(one_chip):
     hbm_fits(compiled)
 
 
+def test_fp_probe_reads_windows_in_one_gather(one_chip):
+    """The served fingerprint kernel at the benchmark's 2^26 slots and a
+    4,096-row launch: the probe windows are one gather, not a per-row
+    loop slicing the table into a ``[4096, 16, 2]`` buffer (a
+    slice-gather of ``(16, 2)`` windows lowers to one: ~5.6 ms a launch
+    on the chip)."""
+    compiled = F.fp_acquire_scan_fused.lower(
+        sds((SMOKE_FP_SLOTS, 2), jnp.uint32, one_chip),
+        bucket_state(SMOKE_FP_SLOTS, one_chip),
+        sds((1, 4096, 3), jnp.uint32, one_chip),
+        sds((1,), jnp.int32, one_chip), *scalars(one_chip)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"dynamic-(update-)?slice\(", text)
+    assert re.search(r"u32\[4096,16,2\]\S* gather\(", text)
+    hbm_fits(compiled)
+
+
 @pytest.mark.parametrize("n", [N, SMOKE_SLOTS])
 def test_sweep_expired_pallas_is_a_tpu_kernel(one_chip, n):
     compiled = sweep_expired_pallas.lower(

@@ -421,6 +421,53 @@ class TestProgramSpans:
             assert sum(n == name for line in lines
                        for n, _, _ in line) == 7
 
+    @pytest.mark.jax_backend
+    def test_fingerprint_store_spans_nest_per_thread(self, tmp_path):
+        """The fingerprint store's served calls under a profiler trace:
+        on the loop thread the hash, then the dispatch inside the bulk
+        call's store-lock span ``drl/acquire_many_fp``; the readback on
+        an executor thread; one of each per call, bulk or flush."""
+        import jax
+
+        from distributedratelimiting.redis_tpu.runtime.fp_store import (
+            FingerprintBucketStore,
+        )
+
+        async def main():
+            store = FingerprintBucketStore(n_slots=1024, clock=ManualClock())
+            keys = [f"k{i}" for i in range(64)]
+            await store.acquire_many(keys, [1] * 64, 100.0, 10.0)
+            await store.acquire("warm", 1, 100.0, 10.0)
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                for _ in range(3):
+                    await store.acquire_many(keys, [1] * 64, 100.0, 10.0)
+                for i in range(2):
+                    await store.acquire(f"a{i}", 1, 100.0, 10.0)
+            finally:
+                await asyncio.to_thread(jax.profiler.stop_trace)
+            await store.aclose()
+
+        run(main())
+        lines = _host_span_lines(str(tmp_path))
+        (loop,) = [line for line in lines
+                   if any(n == "drl/store.hash" for n, _, _ in line)]
+        calls = [(a, b) for n, a, b in loop if n == "drl/acquire_many_fp"]
+        assert len(calls) == 3
+
+        def inside(name):
+            return [sum(1 for n, a, b in loop
+                        if n == name and c0 <= a and b <= c1)
+                    for c0, c1 in calls]
+
+        assert inside("drl/store.hash") == [0] * 3
+        assert inside("drl/store.dispatch") == [1] * 3
+        for name in ("drl/store.hash", "drl/store.dispatch"):
+            assert sum(n == name for n, _, _ in loop) == 5
+        assert not any(n == "drl/store.readback" for n, _, _ in loop)
+        assert sum(n == "drl/store.readback" for line in lines
+                   for n, _, _ in line) == 5
+
 
 # -- end-to-end: wire-propagated span trees ----------------------------------
 
