@@ -911,52 +911,50 @@ class _PackedLaunchMixin:
     #: not measured). K is chosen per call from {1, 2, 4, …, 32}, so the
     #: jit cache holds at most 6 bulk variants per table.
     _BULK_MAX_K = 32
-    #: Profiler span name for the scan-path dispatch (per table family).
+    #: Profiler span name for a bulk call's store-lock section, in both
+    #: plans (per table family): the plan is chosen inside it, from the
+    #: resolved slots.
     _BULK_SPAN = "acquire_many"
 
-    def _launch_many(self, keys: Sequence[str], counts_np: np.ndarray,
-                     with_remaining: bool = True) -> list[tuple]:
-        """Dispatch a whole key array as scanned kernel launches; returns
-        per-dispatch device handles (no readback — callers overlap it).
-        The chunking/padding discipline is shared; the table family's
-        ``_launch_scan_chunk`` runs its own scanned kernel per chunk.
-        u8 counts ride the fused 5-bytes/decision layout (slots + counts
-        in ONE operand — transfer count matters as much as bytes on
-        per-transfer-floor-bound links); rare oversized counts fall back
-        to the split layout with an explicit mask."""
-        n = len(keys)
+    def _launch_many(self, slots: np.ndarray, counts_np: np.ndarray,
+                     with_remaining: bool) -> list[tuple]:
+        """Dispatch a whole resolved slot array as scanned kernel launches
+        (the caller holds the store lock); returns per-dispatch device
+        handles (no readback — callers overlap it). The chunking/padding
+        discipline is shared; the table family's ``_launch_scan_chunk``
+        runs its own scanned kernel per chunk. u8 counts ride the fused
+        5-bytes/decision layout (slots + counts in ONE operand — transfer
+        count matters as much as bytes on per-transfer-floor-bound links);
+        rare oversized counts fall back to the split layout with an
+        explicit mask."""
+        n = len(slots)
         b = self.store.max_batch
         outs: list[tuple] = []
         compact = n > 0 and int(counts_np.max(initial=0)) <= 0xFF
-        with self.store.profiler.span(self._BULK_SPAN, n), self.store._lock:
-            # keys may be a wire.KeyBlob: the native directory resolves
-            # straight from the frame's byte blob (zero Python strings).
-            slots = self.resolve_slots(keys)
-            with annotate("drl/store.dispatch"):
-                now = self.store.now_ticks_checked()
-                pos = 0
-                while pos < n:
-                    rows = -(-(n - pos) // b)  # ceil
-                    k = 1
-                    while k < rows and k < self._BULK_MAX_K:
-                        k *= 2
-                    # The rare int32-count fallback keeps the full width:
-                    # its kernel compiles on first use, not with the table.
-                    w = (self._launch_width(n - pos)
-                         if rows == 1 and compact else b)
-                    take = min(k * w, n - pos)
-                    s = np.full((k * w,), -1, np.int32)
-                    s[:take] = slots[pos:pos + take]
-                    c = np.zeros((k * w,), np.uint8 if compact else np.int32)
-                    c[:take] = np.minimum(counts_np[pos:pos + take],
-                                          2**31 - 1)
-                    nows = np.full((k,), now, np.int32)
-                    out = self._launch_scan_chunk(
-                        s.reshape(k, w), c.reshape(k, w), nows, compact,
-                        with_remaining)
-                    outs.append((out, take))
-                    self.store.metrics.record_launch(k * w, take)
-                    pos += take
+        with annotate("drl/store.dispatch"):
+            now = self.store.now_ticks_checked()
+            pos = 0
+            while pos < n:
+                rows = -(-(n - pos) // b)  # ceil
+                k = 1
+                while k < rows and k < self._BULK_MAX_K:
+                    k *= 2
+                # The rare int32-count fallback keeps the full width:
+                # its kernel compiles on first use, not with the table.
+                w = (self._launch_width(n - pos)
+                     if rows == 1 and compact else b)
+                take = min(k * w, n - pos)
+                s = np.full((k * w,), -1, np.int32)
+                s[:take] = slots[pos:pos + take]
+                c = np.zeros((k * w,), np.uint8 if compact else np.int32)
+                c[:take] = np.minimum(counts_np[pos:pos + take], 2**31 - 1)
+                nows = np.full((k,), now, np.int32)
+                out = self._launch_scan_chunk(
+                    s.reshape(k, w), c.reshape(k, w), nows, compact,
+                    with_remaining)
+                outs.append((out, take))
+                self.store.metrics.record_launch(k * w, take)
+                pos += take
         return outs
 
     def _launch_scan_chunk(self, s: np.ndarray, c: np.ndarray,
@@ -1004,19 +1002,22 @@ class _PackedLaunchMixin:
     @staticmethod
     def _bulk_groups(slots: np.ndarray, counts_np: np.ndarray):
         """Slot-grouped view of a bulk call for duplicate coalescing, or
-        ``None`` when it wouldn't pay. Fully vectorized (stable argsort +
-        segment boundaries — request order is preserved within each slot's
-        segment, which is what makes group decisions bit-identical to the
-        per-row conservative serialization). Declines when <25% of rows
-        would be saved, or when any key's counts are mixed (the scan
-        path's exact prefixes handle that rare shape)."""
+        ``None`` when it wouldn't pay. The decision counts distinct slots
+        with one sort and one compare; only a call that groups pays for
+        the stable argsort + segment boundaries (request order is
+        preserved within each slot's segment, which is what makes group
+        decisions bit-identical to the per-row conservative
+        serialization). Declines when <25% of rows would be saved, or
+        when any key's counts are mixed (the scan path's exact prefixes
+        handle that rare shape)."""
         n = len(slots)
-        order = np.argsort(slots, kind="stable")
-        s_sorted = slots[order]
-        seg_start = np.r_[True, s_sorted[1:] != s_sorted[:-1]]
-        n_groups = int(seg_start.sum())
-        if n_groups * 4 > n * 3:
+        if n < 2:
             return None
+        s_sorted = np.sort(slots)  # what the stable argsort sorts to
+        seg_start = np.r_[True, s_sorted[1:] != s_sorted[:-1]]
+        if int(np.count_nonzero(seg_start)) * 4 > n * 3:
+            return None
+        order = np.argsort(slots, kind="stable")
         starts = np.nonzero(seg_start)[0]
         lengths = np.diff(np.r_[starts, n])
         c_sorted = counts_np[order]
@@ -1027,45 +1028,37 @@ class _PackedLaunchMixin:
         rank = np.arange(n) - starts[seg_id]
         return order, seg_id, rank, starts, lengths, first_c
 
-    def _launch_many_grouped(self, keys: Sequence[str],
-                             counts_np: np.ndarray, with_remaining: bool):
-        """Coalesced bulk dispatch: one launch row per ``(key, count)``
-        group via the grouped flush kernel — under Zipf hot keys the
-        transferred bytes (the bulk path's real cost) shrink by the
-        duplicate fraction. Returns a readback closure, or ``None`` when
-        grouping doesn't pay (caller falls back to the scan path)."""
-        n = len(keys)
-        if n == 0:
-            return None
-        with self.store.profiler.span("acquire_many_grouped", n), \
-                self.store._lock:
-            slots = self.resolve_slots(keys)  # KeyBlob-aware (see above)
-            g = self._bulk_groups(slots, counts_np)
-            if g is None:
-                return None
-            order, seg_id, rank, starts, lengths, first_c = g
-            gslots = slots[order][starts]
-            gcounts = np.minimum(first_c, 2**31 - 1).astype(np.int32)
-            b = self.store.max_batch
-            outs: list[tuple] = []
-            with annotate("drl/store.dispatch"):
-                now = self.store.now_ticks_checked()
-                for pos in range(0, len(gslots), b):
-                    m = min(b, len(gslots) - pos)
-                    w = self._launch_width(m)
-                    packed = np.full((5, w), -1, np.int32)
-                    packed[1] = 0
-                    packed[3] = 0  # one group per slot per call ⇒ prefix 0
-                    packed[4] = 0
-                    packed[0, :m] = gslots[pos:pos + m]
-                    packed[1, :m] = gcounts[pos:pos + m]
-                    packed[2] = now
-                    packed[4, :m] = np.minimum(lengths[pos:pos + m],
-                                               2**31 - 1)
-                    out = self._launch_grouped(jnp.asarray(packed))
-                    outs.append((out, m))
-                    self.store.metrics.record_launch(w, m)
-            self.store.metrics.rows_coalesced += n - len(gslots)
+    def _launch_many_grouped(self, slots: np.ndarray, groups,
+                             with_remaining: bool):
+        """Coalesced bulk dispatch of resolved slots (the caller holds the
+        store lock): one launch row per ``(key, count)`` group via the
+        grouped flush kernel — under Zipf hot keys the transferred bytes
+        (the bulk path's real cost) shrink by the duplicate fraction.
+        ``groups`` is :meth:`_bulk_groups`' view. Returns a readback
+        closure."""
+        n = len(slots)
+        order, seg_id, rank, starts, lengths, first_c = groups
+        gslots = slots[order][starts]
+        gcounts = np.minimum(first_c, 2**31 - 1).astype(np.int32)
+        b = self.store.max_batch
+        outs: list[tuple] = []
+        with annotate("drl/store.dispatch"):
+            now = self.store.now_ticks_checked()
+            for pos in range(0, len(gslots), b):
+                m = min(b, len(gslots) - pos)
+                w = self._launch_width(m)
+                packed = np.full((5, w), -1, np.int32)
+                packed[1] = 0
+                packed[3] = 0  # one group per slot per call ⇒ prefix 0
+                packed[4] = 0
+                packed[0, :m] = gslots[pos:pos + m]
+                packed[1, :m] = gcounts[pos:pos + m]
+                packed[2] = now
+                packed[4, :m] = np.minimum(lengths[pos:pos + m], 2**31 - 1)
+                out = self._launch_grouped(jnp.asarray(packed))
+                outs.append((out, m))
+                self.store.metrics.record_launch(w, m)
+        self.store.metrics.rows_coalesced += n - len(gslots)
 
         def gather() -> BulkAcquireResult:
             n_g = np.empty(len(gslots), np.float32)
@@ -1096,15 +1089,25 @@ class _PackedLaunchMixin:
 
     def _bulk_plan(self, keys: Sequence[str], counts_np: np.ndarray,
                    with_remaining: bool):
-        """Choose + dispatch the bulk strategy; returns the readback
-        closure (callers run it inline or on an executor)."""
-        if self.store.coalesce_duplicates:
-            gather = self._launch_many_grouped(keys, counts_np,
-                                               with_remaining)
-            if gather is not None:
-                return gather
-        outs = self._launch_many(keys, counts_np, with_remaining)
-        return lambda: self._gather_bulk(outs, len(keys), with_remaining)
+        """Resolve the call's keys once, choose the grouped or scanned plan
+        from those slots, and dispatch it — one store-lock section;
+        returns the readback closure (callers run it inline or on an
+        executor)."""
+        n = len(keys)
+        metrics = self.store.metrics
+        with self.store.profiler.span(self._BULK_SPAN, n), self.store._lock:
+            # keys may be a wire.KeyBlob: the native directory resolves
+            # straight from the frame's byte blob (zero Python strings).
+            slots = self.resolve_slots(keys)
+            groups = (self._bulk_groups(slots, counts_np)
+                      if self.store.coalesce_duplicates else None)
+            if groups is not None:
+                metrics.bulk_plans_grouped += 1
+                return self._launch_many_grouped(slots, groups,
+                                                 with_remaining)
+            metrics.bulk_plans_scanned += 1
+            outs = self._launch_many(slots, counts_np, with_remaining)
+        return lambda: self._gather_bulk(outs, n, with_remaining)
 
     def acquire_many_blocking(self, keys: Sequence[str],
                               counts: Sequence[int], *,
@@ -1119,8 +1122,6 @@ class _PackedLaunchMixin:
                            counts: Sequence[int], *,
                            with_remaining: bool = True) -> BulkAcquireResult:
         counts_np = np.asarray(counts, np.int64)
-        # One store_host sample covers both store-lock sections when the
-        # grouped plan declines.
         t0 = time.perf_counter()
         gather = self._bulk_plan(keys, counts_np, with_remaining)
         self.store.metrics.store_host.record(time.perf_counter() - t0)

@@ -261,6 +261,12 @@ class StoreMetrics:
     # Duplicate requests merged away by flush coalescing (requests minus
     # launch rows) — the Zipf hot-key win's direct measure.
     rows_coalesced: int = 0
+    # Bulk calls (acquire_many on the host-directory tables) by the plan
+    # chosen from their resolved slots, one increment per call: grouped
+    # (one launch row per duplicated (key, count)) or scanned (one row per
+    # request; calls with <25% duplicates or mixed counts per key).
+    bulk_plans_grouped: int = 0
+    bulk_plans_scanned: int = 0
     # Table growths (single-chip: background pre-warm compilations;
     # sharded: in-place per-shard doublings).
     pregrows: int = 0
@@ -314,6 +320,8 @@ class StoreMetrics:
             "sweeps": self.sweeps,
             "slots_evicted": self.slots_evicted,
             "rows_coalesced": self.rows_coalesced,
+            "bulk_plans_grouped": self.bulk_plans_grouped,
+            "bulk_plans_scanned": self.bulk_plans_scanned,
             "pregrows": self.pregrows,
             "fp_unresolved": self.fp_unresolved,
             "flush_p50_ms": self.flush_latency.p50 * 1e3,

@@ -427,6 +427,79 @@ class TestBulkAcquire:
         run(main())
 
 
+def _bulk_plan_frame(case: str, rng) -> tuple[list[str], list[int]]:
+    """One bulk call's keys and counts. Every key's balance (6) is a
+    multiple of each count it asks for, and a key's denials come only
+    after its balance is spent, so the serial reference and the bulk
+    path's conservative in-call serialization agree row for row."""
+    if case == "one-row":
+        return ["solo"], [2]
+    if case == "uniform":
+        keys = [f"u{i}" for i in rng.permutation(48)]
+        return keys, [int(c) for c in rng.integers(0, 7, 48)]
+    # Eight hot keys, eight rows each, interleaved: 8 groups of 64 rows.
+    per_key = {f"h{i}": [1 + i % 3] * 8 for i in range(8)}
+    if case == "mixed":
+        per_key["h0"] = [1, 2, 3, 1, 1, 1, 1, 1]
+    order = rng.permutation([k for k in per_key for _ in range(8)])
+    seen = {k: 0 for k in per_key}
+    keys, counts = [], []
+    for k in order:
+        keys.append(str(k))
+        counts.append(per_key[k][seen[k]])
+        seen[k] += 1
+    return keys, counts
+
+
+class TestBulkPlan:
+    """A bulk call resolves its keys through the host directory once, in
+    one store-lock section, and picks the grouped or scanned plan from
+    those slots."""
+
+    @pytest.mark.parametrize("case, plan", [
+        ("uniform", "scanned"), ("duplicated", "grouped"),
+        ("one-row", "scanned"), ("mixed", "scanned")])
+    @pytest.mark.parametrize("kind", ["bucket", "window"])
+    def test_one_resolve_per_call_and_serial_decisions(self, clock, rng,
+                                                       kind, case, plan):
+        dev = device_store(clock, n_slots=128, max_batch=64)
+        ref = InProcessBucketStore(clock=clock)
+        if kind == "bucket":
+            table = dev._table(6.0, 0.0)
+            call = lambda k, c: dev.acquire_many(k, c, 6.0, 0.0)
+            serial = lambda k, c: ref.acquire_blocking(k, c, 6.0, 0.0)
+        else:
+            table = dev._wtable(6.0, 10.0)
+            call = lambda k, c: dev.window_acquire_many(k, c, 6.0, 10.0)
+            serial = lambda k, c: ref.window_acquire_blocking(k, c, 6.0,
+                                                              10.0)
+        resolves = []
+        resolve_batch = table.dir.resolve_batch
+
+        def counted(keys):
+            resolves.append(len(keys))
+            return resolve_batch(keys)
+
+        table.dir.resolve_batch = counted
+        keys, counts = _bulk_plan_frame(case, rng)
+
+        async def main():
+            res = await call(keys, counts)
+            await dev.aclose()
+            return res
+
+        res = run(main())
+        assert resolves == [len(keys)]
+        want = [serial(k, c) for k, c in zip(keys, counts)]
+        assert [bool(g) for g in res.granted] == [w.granted for w in want]
+        assert res.remaining.tolist() == [w.remaining for w in want]
+        snap = dev.metrics.snapshot()
+        assert (snap["bulk_plans_grouped"], snap["bulk_plans_scanned"]) == \
+            ((1, 0) if plan == "grouped" else (0, 1))
+        assert dev.metrics.rows_coalesced == (
+            len(keys) - 8 if plan == "grouped" else 0)
+
+
 class TestBulkLimiter:
     def test_partitioned_acquire_many(self, clock):
         from distributedratelimiting.redis_tpu.models.options import (

@@ -355,9 +355,10 @@ class TestProgramSpans:
     @pytest.mark.jax_backend
     def test_served_path_spans_nest_per_thread(self, tmp_path):
         """A real profiler trace of a native-front-end server over a CPU
-        device store holds every served-path span: resolve and dispatch
-        inside the store-lock span on the loop thread, the readback on
-        an executor thread, the pump's copy on its own."""
+        device store holds every served-path span: one store-lock span
+        per call on the loop thread, holding one resolve and one
+        dispatch, the readback on an executor thread, the pump's copy on
+        its own."""
         import jax
 
         from distributedratelimiting.redis_tpu.utils.native import (
@@ -398,9 +399,10 @@ class TestProgramSpans:
         lines = _host_span_lines(str(tmp_path))
         names = {name for line in lines for name, _, _ in line}
         assert names >= {"drl/fe.pump", "drl/acquire_many",
-                         "drl/acquire_many_grouped", "drl/store.resolve",
-                         "drl/store.dispatch", "drl/store.readback",
-                         "drl/fe.complete"}
+                         "drl/store.resolve", "drl/store.dispatch",
+                         "drl/store.readback", "drl/fe.complete"}
+        # No call carries duplicates: no grouped plan, and no attempt at one.
+        assert "drl/acquire_many_grouped" not in names
         (loop,) = [line for line in lines
                    if any(n == "drl/acquire_many" for n, _, _ in line)]
         calls = [(a, b) for n, a, b in loop if n == "drl/acquire_many"]
@@ -413,9 +415,8 @@ class TestProgramSpans:
 
         assert inside("drl/store.dispatch") == [1] * 7
         assert inside("drl/store.resolve") == [1] * 7
-        grouped = [(a, b) for n, a, b in loop
-                   if n == "drl/acquire_many_grouped"]
-        assert len(grouped) == 7     # the grouped plan declines first
+        # The one store-lock section is the call's only resolve.
+        assert sum(n == "drl/store.resolve" for n, _, _ in loop) == 7
         for name in ("drl/store.readback", "drl/fe.pump"):
             assert not any(n == name for n, _, _ in loop)
             assert sum(n == name for line in lines
