@@ -11,9 +11,6 @@ into benchmark cells and replaces this runner):
 2. ``fp_bulk_optimized`` — the optimized fp bulk path (fused operand,
    bit-plane verdicts) was reworked after the last healthy window; its
    device rate is extrapolated, never observed.
-3. ``native_fe_device_sweep`` — the native front-end has NO number
-   against a device-class (multi-ms flush) backing — the one serving
-   regime the 2 ms p99 north star actually fears.
 
 Running ``python -m benchmarks.recapture`` checks for a device with a
 child process (so device-backed server children can take the chip
@@ -92,53 +89,6 @@ def _debt_fp_bulk_optimized(smoke: bool) -> dict:
             "unit": "decisions/s", "keys_per_call": n}
 
 
-def _debt_native_fe_device_sweep(smoke: bool) -> dict:
-    """The native front-end against a device-backed store, via bench.py's
-    existing child rig (one server process owning the device, one load
-    process driving the C loadgen) — subprocesses so a wedged device op
-    costs this section, not the runner. Round 8 added the BULK arm: the
-    same device-backed server (tier-0 armed) driven with ACQUIRE_MANY
-    frames through the native bulk lane — the native-FE p99 against a
-    multi-ms-flush backing that the 2 ms north star actually fears."""
-    env = os.environ.copy()
-    env.pop("DRL_TPU_FORCE_CPU", None)
-    if smoke:
-        # CPU stand-in exercises the identical rig end to end.
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
-    out: dict = {}
-    for arm, server_args, load_flag, load_args in (
-        ("scalar", ["device", "native"], "--native-load-child", []),
-        ("bulk", ["device", "native", "tier0"], "--bulk-load-child",
-         ["hot"]),
-    ):
-        server = subprocess.Popen(
-            [sys.executable, str(_ROOT / "bench.py"),
-             "--serving-server-child", *server_args],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
-            env=env, cwd=str(_ROOT))
-        try:
-            line = server.stdout.readline()
-            addr = json.loads(line)
-            load = subprocess.run(
-                [sys.executable, str(_ROOT / "bench.py"),
-                 load_flag, addr["host"], str(addr["port"]), *load_args],
-                capture_output=True, text=True, env=env, cwd=str(_ROOT),
-                timeout=1200)
-            if load.returncode != 0:
-                raise RuntimeError(
-                    f"{arm} load child failed: "
-                    f"{load.stderr.strip()[-400:]}")
-            out[arm] = json.loads(load.stdout.strip().splitlines()[-1])
-        finally:
-            try:
-                server.stdin.close()
-                server.wait(30)
-            except Exception:
-                server.kill()
-    return {"metric": "depth_sweep", "sweep": out.get("scalar"),
-            "bulk": out.get("bulk"), "unit": "req/s + ms"}
-
-
 def _debt_llm_workload_device(smoke: bool) -> dict:
     """The LLM workload (ISSUE 10) against the DEVICE store: the fused
     two-level kernel (acquire_hierarchical_packed) deciding the Zipf ×
@@ -211,149 +161,6 @@ def _debt_llm_reservations_device(smoke: bool) -> dict:
             "value": round(n / dt),
             "settled_tokens_per_sec": round(total_tokens / dt),
             "unit": "reserve+settle pairs/s", "rows": n}
-
-
-def _debt_native_fe_shard_sweep(smoke: bool) -> dict:
-    """The multi-shard front-end (round 11) against a DEVICE-class
-    backing: shards ∈ {1, 2, 4, 8} SO_REUSEPORT epoll shards on one
-    port, tier-0 armed, driven by the C bulk loadgen — the node-level
-    rows/s curve whose CPU stand-in lives in
-    evidence/native_shards_r11.jsonl and BENCH serving_native_shards.
-    On a real device the residue rows meet a multi-ms flush, so the
-    device arm is the one that prices the shield, not just the shards."""
-    import concurrent.futures
-
-    env = os.environ.copy()
-    env.pop("DRL_TPU_FORCE_CPU", None)
-    if smoke:
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
-    out: dict = {}
-    for shards in (1, 2, 4, 8):
-        server = subprocess.Popen(
-            [sys.executable, str(_ROOT / "bench.py"),
-             "--serving-server-child", "device", "native", "tier0",
-             f"shards={shards}", "pin"],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
-            env=env, cwd=str(_ROOT))
-        pool = concurrent.futures.ThreadPoolExecutor(1)
-        try:
-            line = pool.submit(server.stdout.readline).result(
-                timeout=180.0)
-            addr = json.loads(line)
-            load = subprocess.run(
-                [sys.executable, str(_ROOT / "bench.py"),
-                 "--shard-load-child", addr["host"],
-                 str(addr["port"]), str(shards)],
-                capture_output=True, text=True, env=env,
-                cwd=str(_ROOT), timeout=600)
-            if load.returncode != 0:
-                raise RuntimeError(
-                    f"s{shards} load child failed: "
-                    f"{load.stderr.strip()[-400:]}")
-            out[f"s{shards}"] = json.loads(
-                load.stdout.strip().splitlines()[-1])
-        finally:
-            try:
-                server.stdin.close()
-                server.wait(30)
-            except Exception:
-                server.kill()
-            pool.shutdown(wait=False)
-    if "s1" in out and "s4" in out:
-        out["speedup_4v1"] = (out["s4"]["rows_per_s"]
-                              / out["s1"]["rows_per_s"])
-    return {"metric": "shard_sweep", "sweep": out,
-            "unit": "rows/s per shard count"}
-
-
-def _debt_native_fe_uring_sweep(smoke: bool) -> dict:
-    """The io_uring data plane (round 16) against a DEVICE-class
-    backing: the round-11 shard rig once per transport arm — epoll vs
-    io_uring vs io_uring+SQPOLL at 1 and 4 shards — harvesting the
-    server child's shutdown line (fe_uring_counts data-plane syscall
-    counter + rusage CPU-seconds) so syscalls/frame and cycles/row get
-    device-backed numbers instead of the CPU stand-ins in
-    evidence/native_uring_r16.jsonl. On a host whose kernel lacks
-    io_uring only the epoll arm runs and the probe verdict is
-    recorded beside it — a fallback run never masquerades as ring
-    numbers (the per-arm rows carry uring_shards/fallbacks)."""
-    import concurrent.futures
-
-    from distributedratelimiting.redis_tpu.runtime.native_frontend import (
-        uring_probe,
-    )
-
-    env = os.environ.copy()
-    env.pop("DRL_TPU_FORCE_CPU", None)
-    if smoke:
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
-    ok, reason = uring_probe()
-    arms = [("epoll", None)]
-    if ok:
-        arms += [("uring", "on"), ("sqpoll", "sqpoll")]
-    out: dict = {"uring_available": ok, "probe": reason}
-    for name, uring in arms:
-        for shards in (1, 4):
-            argv = [sys.executable, str(_ROOT / "bench.py"),
-                    "--serving-server-child", "device", "native",
-                    "tier0", f"shards={shards}", "pin"]
-            if uring is not None:
-                argv.append(f"uring={uring}")
-            server = subprocess.Popen(
-                argv, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-                text=True, env=env, cwd=str(_ROOT))
-            pool = concurrent.futures.ThreadPoolExecutor(1)
-            try:
-                line = pool.submit(server.stdout.readline).result(
-                    timeout=180.0)
-                addr = json.loads(line)
-                load = subprocess.run(
-                    [sys.executable, str(_ROOT / "bench.py"),
-                     "--shard-load-child", addr["host"],
-                     str(addr["port"]), str(shards)],
-                    capture_output=True, text=True, env=env,
-                    cwd=str(_ROOT), timeout=600)
-                if load.returncode != 0:
-                    raise RuntimeError(
-                        f"{name}_s{shards} load child failed: "
-                        f"{load.stderr.strip()[-400:]}")
-                res = json.loads(load.stdout.strip().splitlines()[-1])
-                server.stdin.close()
-                tail = pool.submit(server.stdout.readline).result(
-                    timeout=60.0)
-                if tail.strip():
-                    res.update(json.loads(tail))
-                tr = res.get("transport")
-                if tr and res.get("frames_sent"):
-                    res["syscalls_per_frame"] = round(
-                        tr["io_syscalls"] / res["frames_sent"], 3)
-                # ε-consumption annotation (round 18): the server
-                # child's shutdown line carries the cumulative tier-0
-                # grant tokens and the per-slice split (fe_t0_eps) —
-                # fold them into the per-slice utilization proxy the
-                # conservation auditor renders as
-                # drl_epsilon_budget_used_ratio{source="shard"}, so
-                # each transport arm prices drift beside its syscall
-                # economics.
-                eps = res.get("t0_eps_tokens")
-                if eps and sum(eps) > 0:
-                    res["t0_eps_hot_slice_share"] = round(
-                        max(eps) / sum(eps), 4)
-                grant = res.get("t0_grant_tokens")
-                if grant:
-                    res["t0_overadmit_per_grant"] = round(
-                        res.get("t0_overadmit_total", 0.0) / grant, 9)
-                out[f"{name}_s{shards}"] = res
-            finally:
-                try:
-                    if not server.stdin.closed:
-                        server.stdin.close()
-                    server.wait(30)
-                except Exception:
-                    server.kill()
-                pool.shutdown(wait=False)
-    return {"metric": "uring_transport_sweep", "sweep": out,
-            "unit": "syscalls/frame + rows/s per transport arm"}
 
 
 def _debt_federation_device(smoke: bool) -> dict:
@@ -437,22 +244,11 @@ DEBTS: "list[tuple[str, str, object]]" = [
      "optimized fp bulk (fused operand, bit-plane verdicts) device "
      "rate extrapolated, never observed",
      _debt_fp_bulk_optimized),
-    ("native_fe_device_sweep",
-     "native front-end has no number against a device-class "
-     "(multi-ms flush) backing; round 8 added the "
-     "native-bulk arm (ACQUIRE_MANY through the C lane, tier-0 armed)",
-     _debt_native_fe_device_sweep),
     ("llm_workload_device",
      "the token-denominated LLM workload (ISSUE 10) has no device "
      "number: the fused hierarchical kernel's rows/s + tokens/s rest "
      "on the CPU stand-in (benchmarks/llm_workload.py)",
      _debt_llm_workload_device),
-    ("native_fe_shard_sweep",
-     "the multi-shard front-end (round 11) has no device number: the "
-     "shards x {1,2,4,8} node-level curve rests on the CPU stand-in "
-     "(evidence/native_shards_r11.jsonl); the device arm prices the "
-     "residue path against a real multi-ms flush",
-     _debt_native_fe_shard_sweep),
     ("llm_reservations_device",
      "the estimate-reserve-settle lane (ISSUE 13) has no device "
      "number: reserve = fused hierarchical launch, settle = "
@@ -465,14 +261,6 @@ DEBTS: "list[tuple[str, str, object]]" = [
      "the home's debit_many settle lane under renew reports) rest on "
      "the CPU stand-in (benchmarks/federation.py)",
      _debt_federation_device),
-    ("native_fe_uring_sweep",
-     "the io_uring data plane (round 16) has no device number: the "
-     "epoll/uring/sqpoll transport sweep — syscalls/frame and "
-     "cycles/row against a real multi-ms flush — rests on the CPU "
-     "stand-in (evidence/native_uring_r16.jsonl); round 18 annotates "
-     "each arm with the tier-0 ε-consumption counters (fe_t0_eps "
-     "per-slice grants, overadmit/grant ratio)",
-     _debt_native_fe_uring_sweep),
     ("storm_goodput_device",
      "the retry-storm goodput differential (ISSUE 20) has no device "
      "number: the defended/naive/baseline arms run over the "

@@ -167,26 +167,24 @@ def bench_approximate_1m_zipf(smoke: bool = False) -> dict:
 
     def stage(i):
         # Host-side numpy staging: the timed loop pays the host→device
-        # transfer per dispatch, as production serving does (and as
-        # bench.py's headline measures).
+        # transfer per dispatch, as production serving does.
         slots = _zipf_slots(rng, n_slots, (scan_k, batch))
-        counts = np.ones((scan_k, batch), np.uint8)
+        rows = K.pack_rows(slots, np.ones((scan_k, batch), np.uint8))
         nows = np.arange(scan_k, dtype=np.int32) + 1 + i * scan_k
-        return slots, counts, nows
+        return rows, nows
 
     def dispatch(state, arrays):
-        slots, counts, nows = arrays
-        return K.acquire_scan_compact(
-            state, jnp.asarray(slots), jnp.asarray(counts),
-            jnp.asarray(nows), cap, rate, handle_duplicates=True)
+        rows, nows = arrays
+        return K.acquire_scan(state, jnp.asarray(rows), jnp.asarray(nows),
+                              cap, rate)
 
     staged = [stage(i) for i in range(4)]
-    state, granted, _ = dispatch(state, staged[0])
-    jax.block_until_ready(granted)
+    state, out = dispatch(state, staged[0])
+    jax.block_until_ready(out)
     t0 = time.perf_counter()
     for i in range(iters):
-        state, granted, _ = dispatch(state, staged[i % 4])
-    jax.block_until_ready(granted)
+        state, out = dispatch(state, staged[i % 4])
+    jax.block_until_ready(out)
     device_rate = iters * scan_k * batch / (time.perf_counter() - t0)
 
     # Local hot path: pure in-memory decisions (the approximate design's
@@ -249,33 +247,32 @@ def bench_sliding_window_10m_bursty(smoke: bool = False) -> dict:
 
     def stage(i):
         slots = rng.integers(0, n_slots, (scan_k, batch)).astype(np.int32)
-        counts = np.ones((scan_k, batch), np.uint8)
         # Bursty: batch occupancy ~ Poisson(0.9·B) in bursts, Poisson(0.2·B)
         # between bursts — arrival gaps become padding rows (slot = -1) in
-        # the fixed-shape compact layout.
+        # the fixed-shape row layout.
         lam = batch * (0.9 if (i % 4) < 2 else 0.2)
         occ = np.minimum(rng.poisson(lam, scan_k), batch)
         slots[np.arange(batch)[None, :] >= occ[:, None]] = -1
+        rows = K.pack_rows(slots, np.ones((scan_k, batch), np.uint8))
         nows = np.arange(scan_k, dtype=np.int32) * 37 + 1 + i * scan_k * 37
-        return (slots, counts, nows), int(occ.sum())
+        return (rows, nows), int(occ.sum())
 
     def dispatch(state, arrays):
-        slots, counts, nows = arrays  # np staged; transfer paid in-loop
-        return K.window_acquire_scan_compact(
-            state, jnp.asarray(slots), jnp.asarray(counts),
-            jnp.asarray(nows), limit, window, handle_duplicates=False)
+        rows, nows = arrays  # np staged; transfer paid in-loop
+        return K.window_acquire_scan(state, jnp.asarray(rows),
+                                     jnp.asarray(nows), limit, window)
 
     staged = [stage(i) for i in range(4)]
     (arrays, _) = staged[0]
-    state, granted, _ = dispatch(state, arrays)
-    jax.block_until_ready(granted)
+    state, out = dispatch(state, arrays)
+    jax.block_until_ready(out)
     decided = 0
     t0 = time.perf_counter()
     for i in range(iters):
         arrays, occ = staged[i % 4]
-        state, granted, _ = dispatch(state, arrays)
+        state, out = dispatch(state, arrays)
         decided += occ
-    jax.block_until_ready(granted)
+    jax.block_until_ready(out)
     dt = time.perf_counter() - t0
 
     # The same workload against the MESH store: keyed sliding windows

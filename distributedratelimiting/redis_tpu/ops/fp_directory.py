@@ -240,7 +240,7 @@ def pack_fp12(fps: np.ndarray, counts: np.ndarray) -> np.ndarray:
     """Host-side packing for the fused fp dispatches: ``u32[B, 3]`` =
     (lo, hi, count), padding rows marked by count ``0xFFFFFFFF``. ONE
     operand array per dispatch instead of three (kpair/counts/valid), as
-    :func:`~.kernels.pack_compact5` does. 12 bytes/decision.
+    :func:`~.kernels.pack_rows` does. 12 bytes/decision.
 
     ``fps`` is ``u32[B, 2]`` (padding rows arbitrary), ``counts`` is the
     valid prefix's counts — rows past ``len(counts)`` become padding.
@@ -287,9 +287,9 @@ def fp_acquire_scan_fused_bits(fp, state: K.BucketState, fused_k, nows_k,
                                handle_duplicates: bool = True):
     """Minimum-transfer fp bulk dispatch: ONE fused operand up
     (:func:`pack_fp12`), ONE bit-packed result down — the fp analogue of
-    :func:`~.kernels.acquire_scan_fused_bits`: the verdict-only path
-    ships granted+resolved as two bit-planes in a single
-    ``u8[K, 2, B//8]`` array (one fetch per dispatch).
+    :func:`~.kernels.acquire_scan` with ``verdict_only``: the
+    verdict-only path ships granted+resolved as two bit-planes in a
+    single ``u8[K, 2, B//8]`` array (one fetch per dispatch).
 
     Returns ``(fp, state, bits u8[K, 2, B//8])``; ``B % 8 == 0``.
     """

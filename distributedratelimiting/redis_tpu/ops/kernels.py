@@ -46,12 +46,7 @@ __all__ = [
     "acquire_batch_packed",
     "acquire_batch_packed_grouped",
     "acquire_scan",
-    "acquire_scan_compact",
-    "acquire_scan_compact_packed",
-    "acquire_scan_compact_bits",
-    "acquire_scan_packed24",
-    "pack_slots24",
-    "SLOT24_PAD",
+    "pack_rows",
     "acquire_hierarchical_packed",
     "debit_batch_packed",
     "sync_batch",
@@ -65,7 +60,6 @@ __all__ = [
     "window_acquire_batch_packed",
     "window_acquire_batch_packed_grouped",
     "window_acquire_scan",
-    "window_acquire_scan_compact",
     "sweep_expired",
     "sweep_counters",
     "sweep_windows",
@@ -376,387 +370,95 @@ def acquire_batch_packed_grouped(state: BucketState, packed, capacity,
     return state, jnp.stack([n_granted, remaining])
 
 
-@partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
-def acquire_scan(state: BucketState, slots_k, counts_k, valid_k, nows_k,
-                 capacity, fill_rate_per_tick, *,
-                 handle_duplicates: bool = True):
-    """Pipelined dispatch: K micro-batches decided in ONE kernel launch via
-    ``lax.scan`` — amortizes launch overhead when the host has several
-    flushes queued. Semantics are identical to K sequential
-    :func:`acquire_batch` calls: each scanned batch keeps its own ``now``
-    operand (``nows_k[k]``), preserving the one-timestamp-per-batch
-    time-authority property.
+def pack_rows(slots, counts):
+    """Host-side packing of a scanned launch's rows into ONE operand.
 
-    Shapes: ``slots_k/counts_k/valid_k: [K, B]``, ``nows_k: i32[K]``.
-    Returns ``(new_state, granted [K, B], remaining [K, B])``.
-    """
-
-    def body(st, xs):
-        slots, counts, valid, now = xs
-        st, granted, remaining = acquire_core(
-            st, slots, counts, valid, now, capacity, fill_rate_per_tick,
-            handle_duplicates=handle_duplicates,
-        )
-        return st, (granted, remaining)
-
-    state, (granted, remaining) = jax.lax.scan(
-        body, state, (slots_k, counts_k, valid_k, nows_k)
-    )
-    return state, granted, remaining
-
-
-@partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
-def acquire_scan_compact(state: BucketState, slots_k, counts_k, nows_k,
-                         capacity, fill_rate_per_tick, *,
-                         handle_duplicates: bool = True):
-    """Transfer-minimal scanned dispatch for the throughput path.
-
-    Designed for a host→device *transfer*-bound pipeline (not measured on
-    the chip), where sustained throughput ≈ link bandwidth / bytes-per-
-    decision. This variant ships 5 bytes/decision (i32 slot + u8 count;
-    validity is ``slots >= 0``, so no mask array travels) versus 9-16 for
-    the split/packed layouts. The in-kernel duplicate sort is kept ON by
-    default: it preserves invariant 3 exactly.
-
-    ``counts_k: u8[K, B]`` caps per-request permits at 255 on this path;
-    larger requests belong on the packed serving path
-    (:func:`acquire_batch_packed`, i32 counts).
-
-    Shapes: ``slots_k: i32[K, B]``, ``counts_k: u8[K, B]``,
-    ``nows_k: i32[K]``. Returns ``(new_state, granted bool[K, B],
-    remaining f32[K, B])``.
-    """
-
-    def body(st, xs):
-        slots, counts, now = xs
-        st, granted, remaining = acquire_core(
-            st, slots, counts.astype(jnp.int32), slots >= 0, now, capacity,
-            fill_rate_per_tick, handle_duplicates=handle_duplicates,
-        )
-        return st, (granted, remaining)
-
-    state, (granted, remaining) = jax.lax.scan(
-        body, state, (slots_k, counts_k, nows_k)
-    )
-    return state, granted, remaining
-
-
-@partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
-def acquire_scan_compact_packed(state: BucketState, slots_k, counts_k,
-                                nows_k, capacity, fill_rate_per_tick, *,
-                                handle_duplicates: bool = True):
-    """:func:`acquire_scan_compact` with a SINGLE packed result array.
-
-    The bulk serving path resolves a whole call with ONE device→host
-    fetch: ``out f32[K, 2, B]`` stacks ``granted``
-    (0/1, row 0) and ``remaining`` (row 1) per scanned batch. Same
-    decision semantics as the unpacked variant.
-
-    Returns ``(new_state, out f32[K, 2, B])``.
-    """
-
-    def body(st, xs):
-        slots, counts, now = xs
-        st, granted, remaining = acquire_core(
-            st, slots, counts.astype(jnp.int32), slots >= 0, now, capacity,
-            fill_rate_per_tick, handle_duplicates=handle_duplicates,
-        )
-        return st, jnp.stack([granted.astype(jnp.float32), remaining])
-
-    state, out = jax.lax.scan(body, state, (slots_k, counts_k, nows_k))
-    return state, out
-
-
-@partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
-def acquire_scan_compact_bits(state: BucketState, slots_k, counts_k,
-                              nows_k, capacity, fill_rate_per_tick, *,
-                              handle_duplicates: bool = True):
-    """Verdict-only scanned dispatch: grants return BIT-PACKED.
-
-    For bulk callers that don't need per-request ``remaining`` (admission
-    gates), the result shrinks from 8 bytes/decision to 1 *bit*/decision —
-    ``out u8[K, B//8]``, little-endian bit order (host side:
-    ``np.unpackbits(..., bitorder="little")``).
-    Requires ``B % 8 == 0`` (every batch size here is a power of two).
-
-    Returns ``(new_state, grant_bits u8[K, B//8])``.
-    """
-
-    def body(st, xs):
-        slots, counts, now = xs
-        st, granted, _ = acquire_core(
-            st, slots, counts.astype(jnp.int32), slots >= 0, now, capacity,
-            fill_rate_per_tick, handle_duplicates=handle_duplicates,
-        )
-        bits = (granted.reshape(-1, 8).astype(jnp.uint8)
-                << jnp.arange(8, dtype=jnp.uint8)).sum(
-                    axis=1, dtype=jnp.uint8)
-        return st, bits
-
-    state, out = jax.lax.scan(body, state, (slots_k, counts_k, nows_k))
-    return state, out
-
-
-def _unpack_compact5(fused):
-    """Device-side unpack of the :func:`pack_compact5` layout: LE i32 slot
-    from bytes 0-3 (int32 bit-ops land -1 padding exactly via the sign bit
-    in ``<<24``), count from byte 4."""
-    p = fused.astype(jnp.int32)
-    slots_k = (p[..., 0] | (p[..., 1] << 8) | (p[..., 2] << 16)
-               | (p[..., 3] << 24))
-    return slots_k, p[..., 4]
-
-
-@partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
-def acquire_scan_fused_bits(state: BucketState, fused, nows_k, capacity,
-                            fill_rate_per_tick, *,
-                            handle_duplicates: bool = True):
-    """The bulk serving path's minimum-transfer dispatch: ONE fused
-    operand up (:func:`pack_compact5`, 5 bytes/decision), ONE bit-packed
-    result down (1 bit/decision): one transfer each way per dispatch.
-
-    Returns ``(new_state, grant_bits u8[K, B//8])`` (little-endian bit
-    order, ``B % 8 == 0``)."""
-    slots_k, counts_k = _unpack_compact5(fused)
-
-    def body(st, xs):
-        slots, counts, now = xs
-        st, granted, _ = acquire_core(
-            st, slots, counts, slots >= 0, now, capacity,
-            fill_rate_per_tick, handle_duplicates=handle_duplicates,
-        )
-        bits = (granted.reshape(-1, 8).astype(jnp.uint8)
-                << jnp.arange(8, dtype=jnp.uint8)).sum(
-                    axis=1, dtype=jnp.uint8)
-        return st, bits
-
-    state, out = jax.lax.scan(body, state, (slots_k, counts_k, nows_k))
-    return state, out
-
-
-@partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
-def acquire_scan_fused_packed(state: BucketState, fused, nows_k, capacity,
-                              fill_rate_per_tick, *,
-                              handle_duplicates: bool = True):
-    """Fused-input variant of :func:`acquire_scan_compact_packed`: one
-    operand up, one ``f32[K, 2, B]`` result down (row 0 grants, row 1
-    remaining)."""
-    slots_k, counts_k = _unpack_compact5(fused)
-
-    def body(st, xs):
-        slots, counts, now = xs
-        st, granted, remaining = acquire_core(
-            st, slots, counts, slots >= 0, now, capacity,
-            fill_rate_per_tick, handle_duplicates=handle_duplicates,
-        )
-        return st, jnp.stack([granted.astype(jnp.float32), remaining])
-
-    state, out = jax.lax.scan(body, state, (slots_k, counts_k, nows_k))
-    return state, out
-
-
-#: Padding sentinel for the 24-bit packed slot layout (all-ones 24 bits).
-SLOT24_PAD = (1 << 24) - 1
-
-
-def pack_compact5(slots, counts):
-    """Host-side packing for :func:`acquire_scan_compact_fused`: i32 slot
-    ids (-1 = padding) + u8 counts → little-endian u8[..., 5] (bytes 0-3
-    the slot, byte 4 the count). One array per dispatch: the 5-byte
-    layout travels as ONE operand (the per-transfer cost on the chip is
-    not measured)."""
+    i32 slot ids (-1 = padding) and their counts become little-endian
+    ``u8[..., 5]`` (bytes 0-3 the slot, byte 4 the count) when every
+    count fits a byte, else ``u8[..., 8]`` (bytes 4-7 the count as an
+    i32). A ``uint8`` ``counts`` array fits without a scan; wider counts
+    must fit an i32. Validity travels in the slot's sign, so no mask
+    array goes up."""
     import numpy as np
 
     slots = np.asarray(slots, np.int32)
-    out = np.empty((*slots.shape, 5), np.uint8)
+    counts = np.asarray(counts)
+    narrow = counts.dtype == np.uint8 or not counts.size or (
+        int(counts.min()) >= 0 and int(counts.max()) <= 0xFF)
+    out = np.empty((*slots.shape, 5 if narrow else 8), np.uint8)
     out[..., :4] = slots.astype("<i4").view(np.uint8).reshape(
         *slots.shape, 4)
-    out[..., 4] = counts
+    if narrow:
+        out[..., 4] = counts
+    else:
+        out[..., 4:] = counts.astype("<i4").view(np.uint8).reshape(
+            *slots.shape, 4)
     return out
 
 
-def pack_slots24(slots):
-    """Host-side packing for :func:`acquire_scan_packed24`: i32 slot ids
-    (or ``SLOT24_PAD`` for padding rows) → little-endian u8[..., 3].
-    Vectorized numpy; ~0.8ms for a [32, 8192] stage — off the device
-    critical path (staging overlaps dispatches)."""
-    import numpy as np
-
-    slots = np.asarray(slots)
-    if slots.size and (slots.min() < 0 or slots.max() > SLOT24_PAD):
-        # Out-of-range ids would silently truncate to SOME in-range slot —
-        # debiting an unrelated key's bucket. Fail at pack time instead.
-        raise ValueError(
-            f"slot ids must be within [0, {SLOT24_PAD}] (SLOT24_PAD = "
-            "padding); use acquire_scan_compact for larger tables"
-        )
-    out = np.empty((*slots.shape, 3), np.uint8)
-    out[..., 0] = slots & 0xFF
-    out[..., 1] = (slots >> 8) & 0xFF
-    out[..., 2] = (slots >> 16) & 0xFF
-    return out
+def _le_i32(p, at):
+    """The LE i32 in bytes ``at``..``at + 3`` of int32-widened rows (the
+    sign bit lands through ``<< 24``, so -1 padding comes back exactly)."""
+    return (p[..., at] | (p[..., at + 1] << 8) | (p[..., at + 2] << 16)
+            | (p[..., at + 3] << 24))
 
 
-@partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
-def acquire_scan_compact_fused(state: BucketState, fused, nows_k, capacity,
-                               fill_rate_per_tick, *,
-                               handle_duplicates: bool = True):
-    """:func:`acquire_scan_compact` with slots + counts fused into ONE
-    operand array: ``fused u8[K, B, 5]`` from :func:`pack_compact5`.
-    Decision semantics identical; transfer count per dispatch drops from
-    two arrays to one. Padding rows carry slot -1 (all-ones bytes 0-3).
+def _unpack_rows(rows):
+    """Device-side unpack of :func:`pack_rows`: ``(slots, counts)`` as
+    i32, the count's width read from the static trailing dimension."""
+    p = rows.astype(jnp.int32)
+    counts = p[..., 4] if rows.shape[-1] == 5 else _le_i32(p, 4)
+    return _le_i32(p, 0), counts
 
-    Returns ``(new_state, granted bool[K, B], remaining f32[K, B])``.
-    """
-    slots_k, counts_k = _unpack_compact5(fused)
+
+def _scan_rows(decide, state, rows, nows_k, verdict_only):
+    """The one scan driver: K batches of :func:`pack_rows` rows decided in
+    ONE launch via ``lax.scan``, each with its own ``now`` (the
+    one-timestamp-per-batch time authority), exactly as K sequential
+    batch calls. ``decide(st, slots, counts, valid, now)`` is a batch
+    core. Output: ``f32[K, 2, B]`` (row 0 grants as 0/1, row 1
+    remaining), or with ``verdict_only`` the grants bit-packed into
+    ``u8[K, B//8]``, little-endian bit order (``B % 8 == 0``; host side
+    ``np.unpackbits(..., bitorder="little")``)."""
+    slots_k, counts_k = _unpack_rows(rows)
 
     def body(st, xs):
         slots, counts, now = xs
-        st, granted, remaining = acquire_core(
-            st, slots, counts, slots >= 0, now, capacity,
-            fill_rate_per_tick, handle_duplicates=handle_duplicates,
-        )
-        return st, (granted, remaining)
-
-    state, (granted, remaining) = jax.lax.scan(
-        body, state, (slots_k, counts_k, nows_k)
-    )
-    return state, granted, remaining
-
-
-@partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
-def acquire_scan_packed24(state: BucketState, packed, nows_k, capacity,
-                          fill_rate_per_tick, *,
-                          handle_duplicates: bool = True):
-    """Minimum-transfer scanned dispatch: 3 bytes per decision.
-
-    For a host→device transfer-bound pipeline (not measured on the
-    chip), the headline throughput path packs each unit-permit request
-    into a 24-bit slot id:
-    ``packed: u8[K, B, 3]`` little-endian, :data:`SLOT24_PAD` = padding.
-    Requires ``n_slots < 2**24 - 1`` (16.7M keys/table — the 10M-key
-    BASELINE target fits; larger tables use :func:`acquire_scan_compact`).
-
-    Every request asks exactly 1 permit — the canonical rate-limit
-    request. Mixed-count batches belong on the compact or packed paths.
-    Duplicate serialization stays ON by default: invariant 3 holds
-    exactly.
-
-    Returns ``(new_state, granted bool[K, B], remaining f32[K, B])``.
-    """
-    p = packed.astype(jnp.int32)
-    slots_k = p[..., 0] | (p[..., 1] << 8) | (p[..., 2] << 16)
-
-    def body(st, xs):
-        slots, now = xs
-        valid = slots != SLOT24_PAD
-        st, granted, remaining = acquire_core(
-            st, slots, jnp.ones_like(slots), valid, now, capacity,
-            fill_rate_per_tick, handle_duplicates=handle_duplicates,
-        )
-        return st, (granted, remaining)
-
-    state, (granted, remaining) = jax.lax.scan(
-        body, state, (slots_k, nows_k)
-    )
-    return state, granted, remaining
-
-
-@partial(jax.jit, donate_argnums=0,
-         static_argnames=("handle_duplicates", "interpolate"))
-def window_acquire_scan(state: WindowState, slots_k, counts_k, valid_k,
-                        nows_k, limit, window_ticks, *,
-                        handle_duplicates: bool = True,
-                        interpolate: bool = True):
-    """Pipelined sliding-window dispatch: K micro-batches in ONE launch via
-    ``lax.scan`` — the window analogue of :func:`acquire_scan`, with the
-    same per-batch ``now`` time-authority property. ``interpolate=False``
-    gives fixed-window semantics."""
-
-    def body(st, xs):
-        slots, counts, valid, now = xs
-        st, granted, remaining = _window_acquire_core(
-            st, slots, counts, valid, now, limit, window_ticks,
-            handle_duplicates=handle_duplicates, interpolate=interpolate,
-        )
-        return st, (granted, remaining)
-
-    state, (granted, remaining) = jax.lax.scan(
-        body, state, (slots_k, counts_k, valid_k, nows_k)
-    )
-    return state, granted, remaining
-
-
-@partial(jax.jit, donate_argnums=0,
-         static_argnames=("handle_duplicates", "interpolate"))
-def window_acquire_scan_fused_bits(state: WindowState, fused, nows_k,
-                                   limit, window_ticks, *,
-                                   handle_duplicates: bool = True,
-                                   interpolate: bool = True):
-    """Verdict-only fused window dispatch: 1 bit/decision down (the window
-    analogue of :func:`acquire_scan_fused_bits`; ``B % 8 == 0``)."""
-    slots_k, counts_k = _unpack_compact5(fused)
-
-    def body(st, xs):
-        slots, counts, now = xs
-        st, granted, _ = _window_acquire_core(
-            st, slots, counts, slots >= 0, now, limit, window_ticks,
-            handle_duplicates=handle_duplicates, interpolate=interpolate,
-        )
-        bits = (granted.reshape(-1, 8).astype(jnp.uint8)
-                << jnp.arange(8, dtype=jnp.uint8)).sum(
-                    axis=1, dtype=jnp.uint8)
-        return st, bits
-
-    state, out = jax.lax.scan(body, state, (slots_k, counts_k, nows_k))
-    return state, out
-
-
-@partial(jax.jit, donate_argnums=0,
-         static_argnames=("handle_duplicates", "interpolate"))
-def window_acquire_scan_fused_packed(state: WindowState, fused, nows_k,
-                                     limit, window_ticks, *,
-                                     handle_duplicates: bool = True,
-                                     interpolate: bool = True):
-    """The window bulk path's minimum-transfer dispatch: ONE fused operand
-    up (:func:`pack_compact5`), ONE ``f32[K, 2, B]`` result down (row 0
-    grants, row 1 remaining) — the window analogue of
-    :func:`acquire_scan_fused_packed`. ``interpolate=False`` = fixed
-    windows."""
-    slots_k, counts_k = _unpack_compact5(fused)
-
-    def body(st, xs):
-        slots, counts, now = xs
-        st, granted, remaining = _window_acquire_core(
-            st, slots, counts, slots >= 0, now, limit, window_ticks,
-            handle_duplicates=handle_duplicates, interpolate=interpolate,
-        )
+        st, granted, remaining = decide(st, slots, counts, slots >= 0, now)
+        if verdict_only:
+            return st, (granted.reshape(-1, 8).astype(jnp.uint8)
+                        << jnp.arange(8, dtype=jnp.uint8)).sum(
+                            axis=1, dtype=jnp.uint8)
         return st, jnp.stack([granted.astype(jnp.float32), remaining])
 
-    state, out = jax.lax.scan(body, state, (slots_k, counts_k, nows_k))
-    return state, out
+    return jax.lax.scan(body, state, (slots_k, counts_k, nows_k))
 
 
-@partial(jax.jit, donate_argnums=0, static_argnames=("handle_duplicates",))
-def window_acquire_scan_compact(state: WindowState, slots_k, counts_k,
-                                nows_k, limit, window_ticks, *,
-                                handle_duplicates: bool = True):
-    """Transfer-minimal scanned sliding-window dispatch — the window
-    analogue of :func:`acquire_scan_compact`: 5 bytes/decision (i32 slot +
-    u8 count), validity implied by slot sign, per-batch ``now`` operands."""
+@partial(jax.jit, donate_argnums=0, static_argnames=("verdict_only",))
+def acquire_scan(state: BucketState, rows, nows_k, capacity,
+                 fill_rate_per_tick, *, verdict_only: bool = False):
+    """The bulk path's token-bucket kernel: K batches of :func:`pack_rows`
+    rows (``u8[K, B, 5 | 8]``, ``nows_k: i32[K]``) decided in one launch,
+    one operand up and one result down (:func:`_scan_rows`).
 
-    def body(st, xs):
-        slots, counts, now = xs
-        st, granted, remaining = _window_acquire_core(
-            st, slots, counts.astype(jnp.int32), slots >= 0, now, limit,
-            window_ticks, handle_duplicates=handle_duplicates,
-        )
-        return st, (granted, remaining)
+    Returns ``(new_state, out)``: ``out`` is ``f32[K, 2, B]``, or
+    ``u8[K, B//8]`` grant bits with ``verdict_only``."""
+    decide = partial(acquire_core, capacity=capacity,
+                     fill_rate_per_tick=fill_rate_per_tick)
+    return _scan_rows(decide, state, rows, nows_k, verdict_only)
 
-    state, (granted, remaining) = jax.lax.scan(
-        body, state, (slots_k, counts_k, nows_k)
-    )
-    return state, granted, remaining
+
+@partial(jax.jit, donate_argnums=0,
+         static_argnames=("interpolate", "verdict_only"))
+def window_acquire_scan(state: WindowState, rows, nows_k, limit,
+                        window_ticks, *, interpolate: bool = True,
+                        verdict_only: bool = False):
+    """The window analogue of :func:`acquire_scan`: the same operand and
+    results. ``interpolate=False`` gives fixed-window semantics."""
+    decide = partial(_window_acquire_core, limit=limit,
+                     window_ticks=window_ticks, interpolate=interpolate)
+    return _scan_rows(decide, state, rows, nows_k, verdict_only)
+
 
 
 @partial(jax.jit, donate_argnums=0)

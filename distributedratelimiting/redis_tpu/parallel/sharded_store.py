@@ -215,7 +215,7 @@ def make_two_level_scan_step(mesh, *, handle_duplicates: bool = True):
     """Scanned variant of :func:`make_two_level_step`: K micro-batches per
     launch (``lax.scan`` inside each shard's block), one psum + global-
     counter decay per scanned batch. Amortizes per-dispatch host overhead
-    the same way :func:`~.ops.kernels.acquire_scan_compact` does on one
+    the same way :func:`~.ops.kernels.acquire_scan` does on one
     chip — the sharded path is dispatch-bound at small per-step work, so
     scanning multiplies multi-chip throughput without touching semantics
     (each batch keeps its own ``now``; the global counter sees batches in

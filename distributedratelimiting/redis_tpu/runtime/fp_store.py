@@ -17,7 +17,7 @@ decides it in the SAME launch. What this buys over the host directory:
 
 The trade (made explicit, not hidden): requests ship 8-byte fingerprints
 instead of packed slot ids, so per-decision transfer is larger than the
-packed24 host-directory path, while this store saves host CPU and
+host-directory path's 5-byte rows, while this store saves host CPU and
 memory (the SURVEY.md §7 "device-side hashing/eviction/TTL" regime). At
 10M uniformly drawn keys in 4,096-key bulk calls on one TPU v5e this store
 decides more per second than the host directory (benchmark cells

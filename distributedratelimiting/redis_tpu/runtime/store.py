@@ -921,12 +921,10 @@ class _PackedLaunchMixin:
         """Dispatch a whole resolved slot array as scanned kernel launches
         (the caller holds the store lock); returns per-dispatch device
         handles (no readback — callers overlap it). The chunking/padding
-        discipline is shared; the table family's ``_launch_scan_chunk``
-        runs its own scanned kernel per chunk. u8 counts ride the fused
-        5-bytes/decision layout (slots + counts in ONE operand — transfer
-        count matters as much as bytes on per-transfer-floor-bound links);
-        rare oversized counts fall back to the split layout with an
-        explicit mask."""
+        discipline is shared; each chunk goes up as ONE
+        :func:`~.ops.kernels.pack_rows` operand to the table family's scan
+        kernel (``_scan``): 5 bytes a decision where the counts fit a
+        byte, else 8."""
         n = len(slots)
         b = self.store.max_batch
         outs: list[tuple] = []
@@ -939,8 +937,8 @@ class _PackedLaunchMixin:
                 k = 1
                 while k < rows and k < self._BULK_MAX_K:
                     k *= 2
-                # The rare int32-count fallback keeps the full width:
-                # its kernel compiles on first use, not with the table.
+                # Oversized counts keep the full width: their 8-byte
+                # kernel compiles on first use, not with the table.
                 w = (self._launch_width(n - pos)
                      if rows == 1 and compact else b)
                 take = min(k * w, n - pos)
@@ -950,19 +948,25 @@ class _PackedLaunchMixin:
                 c[:take] = np.minimum(counts_np[pos:pos + take], 2**31 - 1)
                 nows = np.full((k,), now, np.int32)
                 out = self._launch_scan_chunk(
-                    s.reshape(k, w), c.reshape(k, w), nows, compact,
-                    with_remaining)
+                    K.pack_rows(s.reshape(k, w), c.reshape(k, w)), nows,
+                    not with_remaining and w % 8 == 0)
                 outs.append((out, take))
                 self.store.metrics.record_launch(k * w, take)
                 pos += take
         return outs
 
-    def _launch_scan_chunk(self, s: np.ndarray, c: np.ndarray,
-                           nows: np.ndarray, compact: bool,
-                           with_remaining: bool):
+    def _launch_scan_chunk(self, rows: np.ndarray, nows: np.ndarray,
+                           verdict_only: bool):
         """One chunk's scanned dispatch — returns a device handle whose
         layout ``_gather_bulk`` understands (u8 bit-packed grants or
         ``f32[K, 2, B]``)."""
+        self.state, out = self._scan(self.state, jnp.asarray(rows),
+                                     jnp.asarray(nows), verdict_only)
+        return out
+
+    def _scan(self, state, rows, nows, verdict_only: bool):
+        """The table family's scan kernel on ``state``: returns
+        ``(new_state, out)``."""
         raise NotImplementedError
 
     @staticmethod
@@ -1217,42 +1221,36 @@ class _DeviceTable(_PackedLaunchMixin):
             if self.store.coalesce_duplicates:
                 state, out = K.acquire_batch_packed_grouped(
                     state, jnp.asarray(packed), cap, rate)
-            fused = jnp.asarray(K.pack_compact5(
-                packed[:1], np.zeros((1, w), np.uint8)))
-            nows = jnp.zeros((1,), jnp.int32)
-            state, out = K.acquire_scan_fused_packed(state, fused, nows,
-                                                     cap, rate)
-            if w % 8 == 0:
-                state, out = K.acquire_scan_fused_bits(state, fused, nows,
-                                                       cap, rate)
+            state, out = self._warm_scan(state, 1, w)
         jax.block_until_ready(out)
         return state
+
+    def _warm_scan(self, state: K.BucketState, k: int, w: int):
+        """Run the bulk scan kernel once on ``k × w`` padding rows, in
+        both verdict forms (bits only where ``w % 8 == 0``)."""
+        rows = jnp.asarray(K.pack_rows(np.full((k, w), -1, np.int32),
+                                       np.zeros((k, w), np.uint8)))
+        nows = jnp.zeros((k,), jnp.int32)
+        for verdict_only in (False, True) if w % 8 == 0 else (False,):
+            state, out = self._scan(state, rows, nows, verdict_only)
+        return state, out
 
     def _warm_for_size(self, n_slots: int) -> None:
         """One dummy pass of every serving+sweep kernel at ``n_slots`` —
         populates the jit cache for the post-grow shapes. Includes the
-        K=``_BULK_MAX_K`` scan variants (the large-``acquire_many`` shape)
-        so the first post-grow bulk call doesn't hit the ~1s recompile
-        cliff the pregrow machinery exists to remove; smaller tail-K
-        chunks may still compile lazily (cheaper, and off the common
-        path). The dummy state is freed eagerly at the end — the warm
-        runs concurrently with the live table, so holding it would keep
-        transient device memory at ~3× through the 75%-occupancy window."""
-        b = self.store.max_batch
+        bulk scan kernel at K=``_BULK_MAX_K`` in both verdict forms (the
+        large-``acquire_many`` shape) so the first post-grow bulk call
+        doesn't hit the ~1s recompile cliff the pregrow machinery exists
+        to remove; smaller tail-K chunks may still compile lazily
+        (cheaper, and off the common path). The dummy state is freed
+        eagerly at the end — the warm runs concurrently with the live
+        table, so holding it would keep transient device memory at ~3×
+        through the 75%-occupancy window."""
         state = self._warm_launches(K.init_bucket_state(n_slots))
         state, _ = K.sweep_expired(state, jnp.int32(0), self.cap_dev,
                                    self.rate_dev)
-        k = self._BULK_MAX_K
-        s = np.full((k, b), -1, np.int32)
-        nows = np.zeros((k,), np.int32)
-        c8 = np.zeros((k, b), np.uint8)
-        state, out = K.acquire_scan_compact_packed(
-            state, jnp.asarray(s), jnp.asarray(c8), jnp.asarray(nows),
-            self.cap_dev, self.rate_dev)
-        if b % 8 == 0:
-            state, out = K.acquire_scan_compact_bits(
-                state, jnp.asarray(s), jnp.asarray(c8), jnp.asarray(nows),
-                self.cap_dev, self.rate_dev)
+        state, out = self._warm_scan(state, self._BULK_MAX_K,
+                                     self.store.max_batch)
         jax.block_until_ready(out)
         for arr in state:
             arr.delete()
@@ -1353,30 +1351,9 @@ class _DeviceTable(_PackedLaunchMixin):
             return out
 
     # -- bulk decision path (chunk loop shared via _PackedLaunchMixin) -----
-    def _launch_scan_chunk(self, s: np.ndarray, c: np.ndarray,
-                           nows: np.ndarray, compact: bool,
-                           with_remaining: bool):
-        k, b = s.shape
-        if compact:
-            fused = jnp.asarray(K.pack_compact5(s, c))
-            if not with_remaining and b % 8 == 0:
-                self.state, out = K.acquire_scan_fused_bits(
-                    self.state, fused, jnp.asarray(nows),
-                    self.cap_dev, self.rate_dev,
-                )
-            else:
-                self.state, out = K.acquire_scan_fused_packed(
-                    self.state, fused, jnp.asarray(nows),
-                    self.cap_dev, self.rate_dev,
-                )
-            return out
-        self.state, granted, remaining = K.acquire_scan(
-            self.state, jnp.asarray(s), jnp.asarray(c),
-            jnp.asarray(s >= 0), jnp.asarray(nows),
-            self.cap_dev, self.rate_dev,
-        )
-        # One lazy device op so the fetch stays single.
-        return jnp.stack([granted.astype(jnp.float32), remaining], axis=1)
+    def _scan(self, state, rows, nows, verdict_only: bool):
+        return K.acquire_scan(state, rows, nows, self.cap_dev, self.rate_dev,
+                              verdict_only=verdict_only)
 
     def _debit_launch(self, keys: Sequence[str], amounts: Sequence[float]):
         """One saturating-debit launch (tier-0 reconciliation): refill,
@@ -1588,31 +1565,10 @@ class _DeviceWindowTable(_PackedLaunchMixin):
     # -- bulk path (chunk loop shared via _PackedLaunchMixin) --------------
     _BULK_SPAN = "window_acquire_many"
 
-    def _launch_scan_chunk(self, s: np.ndarray, c: np.ndarray,
-                           nows: np.ndarray, compact: bool,
-                           with_remaining: bool):
-        k, b = s.shape
-        if compact:
-            fused = jnp.asarray(K.pack_compact5(s, c))
-            if not with_remaining and b % 8 == 0:
-                self.state, out = K.window_acquire_scan_fused_bits(
-                    self.state, fused, jnp.asarray(nows),
-                    self.limit_dev, self.window_dev,
-                    interpolate=not self.fixed,
-                )
-            else:
-                self.state, out = K.window_acquire_scan_fused_packed(
-                    self.state, fused, jnp.asarray(nows),
-                    self.limit_dev, self.window_dev,
-                    interpolate=not self.fixed,
-                )
-            return out
-        self.state, granted, remaining = K.window_acquire_scan(
-            self.state, jnp.asarray(s), jnp.asarray(c),
-            jnp.asarray(s >= 0), jnp.asarray(nows),
-            self.limit_dev, self.window_dev, interpolate=not self.fixed,
-        )
-        return jnp.stack([granted.astype(jnp.float32), remaining], axis=1)
+    def _scan(self, state, rows, nows, verdict_only: bool):
+        return K.window_acquire_scan(
+            state, rows, nows, self.limit_dev, self.window_dev,
+            interpolate=not self.fixed, verdict_only=verdict_only)
 
 
 class DeviceBucketStore(BucketStore):

@@ -1,7 +1,7 @@
 """JAX's persistent compile cache, placed from outside or at a fixed path.
 
 Every entry point that compiles for the chip (``chip_smoke.py``,
-``bench.py``, the store server, ``examples/testapp.py``) calls
+``perfbench/harness.py``, the store server, ``examples/testapp.py``) calls
 :func:`enable_compile_cache` once, before its first compile:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself; nothing here

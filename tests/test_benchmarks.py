@@ -54,10 +54,8 @@ def test_recapture_debt_ledger_semantics(tmp_path):
 
     names = [n for n, _why, _fn in recapture.DEBTS]
     assert names == ["fp_mesh_fixed", "fp_bulk_optimized",
-                     "native_fe_device_sweep", "llm_workload_device",
-                     "native_fe_shard_sweep",
-                     "llm_reservations_device", "federation_device",
-                     "native_fe_uring_sweep", "storm_goodput_device"]
+                     "llm_workload_device", "llm_reservations_device",
+                     "federation_device", "storm_goodput_device"]
     ledger = tmp_path / "recapture.jsonl"
     assert recapture.owed(ledger) == names  # nothing settled yet
     recapture._append(ledger, {"debt": names[0], "status": "ok",

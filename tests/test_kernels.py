@@ -288,12 +288,26 @@ class TestAuxSweepsAndRebase:
         assert float(est[0]) == 3.0
 
 
+def _scan_out(out):
+    """``(granted, remaining)`` of a scan kernel's ``f32[K, 2, B]``."""
+    out = np.asarray(out)
+    return out[:, 0] > 0.5, out[:, 1]
+
+
+def _bits(granted):
+    return np.packbits(granted, axis=-1, bitorder="little")
+
+
+def _rows8(slots, counts):
+    """The 8-byte row layout of the same rows, whatever the counts."""
+    return np.concatenate(
+        [K.pack_rows(slots, np.zeros_like(counts, np.uint8))[..., :4],
+         np.asarray(counts).astype("<i4")[..., None].view(np.uint8)],
+        axis=-1)
+
+
 class TestAcquireScanCompact:
     def test_matches_sequential_batches(self):
-        import numpy as np
-        import jax.numpy as jnp
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
         rng = np.random.default_rng(7)
         n, b, k = 128, 32, 4
         slots = rng.integers(0, n, (k, b)).astype(np.int32)
@@ -302,9 +316,10 @@ class TestAcquireScanCompact:
         nows = np.arange(1, k + 1, dtype=np.int32) * 10
 
         s1 = K.init_bucket_state(n)
-        s1, granted, remaining = K.acquire_scan_compact(
-            s1, jnp.asarray(slots), jnp.asarray(counts), jnp.asarray(nows),
+        s1, out = K.acquire_scan(
+            s1, jnp.asarray(K.pack_rows(slots, counts)), jnp.asarray(nows),
             jnp.float32(6.0), jnp.float32(0.5))
+        granted, remaining = _scan_out(out)
 
         s2 = K.init_bucket_state(n)
         for i in range(k):
@@ -312,185 +327,118 @@ class TestAcquireScanCompact:
                 s2, jnp.asarray(slots[i]), jnp.asarray(counts[i], jnp.int32),
                 jnp.ones((b,), bool), jnp.int32(nows[i]), jnp.float32(6.0),
                 jnp.float32(0.5))
-            np.testing.assert_array_equal(np.asarray(granted[i]),
-                                          np.asarray(g2))
-            np.testing.assert_allclose(np.asarray(remaining[i]),
-                                       np.asarray(r2), rtol=1e-6)
+            np.testing.assert_array_equal(granted[i], np.asarray(g2))
+            np.testing.assert_allclose(remaining[i], np.asarray(r2),
+                                       rtol=1e-6)
         np.testing.assert_allclose(np.asarray(s1.tokens),
                                    np.asarray(s2.tokens), rtol=1e-6)
 
     def test_negative_slot_is_padding(self):
-        import numpy as np
-        import jax.numpy as jnp
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
         s = K.init_bucket_state(16)
         slots = np.array([[0, -1, 3]], np.int32)
         counts = np.ones((1, 3), np.uint8)
-        s, granted, _ = K.acquire_scan_compact(
-            s, jnp.asarray(slots), jnp.asarray(counts),
+        s, out = K.acquire_scan(
+            s, jnp.asarray(K.pack_rows(slots, counts)),
             jnp.asarray([1], np.int32), jnp.float32(5.0), jnp.float32(0.1))
-        assert list(np.asarray(granted[0])) == [True, False, True]
+        assert list(_scan_out(out)[0][0]) == [True, False, True]
+        # Padding touched nothing: only slots 0 and 3 exist.
+        assert list(np.nonzero(np.asarray(s.exists))[0]) == [0, 3]
 
 
 class TestAcquireScanCompactFused:
     def test_matches_unfused(self):
-        import numpy as np
-        import jax.numpy as jnp
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
+        """5-byte and 8-byte rows of the same requests decide
+        identically."""
         rng = np.random.default_rng(13)
-        n, b, k = 70_000, 32, 4  # n > 2**16: exercises all four slot bytes
+        n, b, k = 70_000, 32, 4  # n > 2**16: exercises three slot bytes
         slots = rng.integers(0, n, (k, b)).astype(np.int32)
         slots[0, :3] = 5          # duplicates
         slots[1, :2] = -1         # padding rows
         counts = rng.integers(0, 255, (k, b)).astype(np.uint8)
         nows = np.arange(1, k + 1, dtype=np.int32) * 10
 
+        rows5 = K.pack_rows(slots, counts)
+        rows8 = _rows8(slots, counts)
+        assert rows5.shape[-1] == 5 and rows8.shape[-1] == 8
         s1 = K.init_bucket_state(n)
-        s1, g1, r1 = K.acquire_scan_compact(
-            s1, jnp.asarray(slots), jnp.asarray(counts), jnp.asarray(nows),
+        s1, out1 = K.acquire_scan(
+            s1, jnp.asarray(rows5), jnp.asarray(nows),
             jnp.float32(200.0), jnp.float32(0.5))
         s2 = K.init_bucket_state(n)
-        s2, g2, r2 = K.acquire_scan_compact_fused(
-            s2, jnp.asarray(K.pack_compact5(slots, counts)),
-            jnp.asarray(nows), jnp.float32(200.0), jnp.float32(0.5))
-        np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
-        np.testing.assert_allclose(np.asarray(r1), np.asarray(r2), rtol=1e-6)
-        np.testing.assert_allclose(np.asarray(s1.tokens),
-                                   np.asarray(s2.tokens), rtol=1e-6)
+        s2, out2 = K.acquire_scan(
+            s2, jnp.asarray(rows8), jnp.asarray(nows),
+            jnp.float32(200.0), jnp.float32(0.5))
+        np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
+        for a, b_ in zip(s1, s2):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
 
     def test_window_fused_bits_matches_packed(self):
-        """The window verdict-only bit-packed path (production default for
-        window bulk with_remaining=False) must agree with the packed-result
-        fused variant bit for bit, for both window families."""
-        import numpy as np
-        import jax.numpy as jnp
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
+        """The window verdict-only bit-packed form (window bulk calls with
+        with_remaining=False) agrees bit for bit with the full result,
+        for both window families."""
         rng = np.random.default_rng(19)
         n, b, k = 300, 64, 3
         slots = rng.integers(0, n, (k, b)).astype(np.int32)
         slots[1, :5] = -1
         counts = rng.integers(0, 3, (k, b)).astype(np.uint8)
         nows = np.arange(1, k + 1, dtype=np.int32) * 400
-        fused = jnp.asarray(K.pack_compact5(slots, counts))
+        rows = jnp.asarray(K.pack_rows(slots, counts))
         for interpolate in (True, False):
             s1 = K.init_window_state(n)
-            s1, out = K.window_acquire_scan_fused_packed(
-                s1, fused, jnp.asarray(nows), jnp.float32(3.0),
+            s1, out = K.window_acquire_scan(
+                s1, rows, jnp.asarray(nows), jnp.float32(3.0),
                 jnp.int32(1024), interpolate=interpolate)
-            want = np.asarray(out)[:, 0, :].reshape(-1) > 0.5
             s2 = K.init_window_state(n)
-            s2, bits = K.window_acquire_scan_fused_bits(
-                s2, fused, jnp.asarray(nows), jnp.float32(3.0),
-                jnp.int32(1024), interpolate=interpolate)
-            got = np.unpackbits(np.asarray(bits).reshape(-1),
-                                bitorder="little")[:k * b].astype(bool)
-            np.testing.assert_array_equal(got, want)
+            s2, bits = K.window_acquire_scan(
+                s2, rows, jnp.asarray(nows), jnp.float32(3.0),
+                jnp.int32(1024), interpolate=interpolate, verdict_only=True)
+            np.testing.assert_array_equal(np.asarray(bits),
+                                          _bits(_scan_out(out)[0]))
             np.testing.assert_allclose(np.asarray(s1.curr_count),
                                        np.asarray(s2.curr_count), rtol=1e-6)
 
     def test_fused_bits_matches_compact_bits(self):
-        import numpy as np
-        import jax.numpy as jnp
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
+        """The bucket verdict-only form agrees with the full result."""
         rng = np.random.default_rng(17)
         n, b, k = 500, 64, 3
         slots = rng.integers(0, n, (k, b)).astype(np.int32)
         slots[2, :8] = -1
         counts = rng.integers(1, 4, (k, b)).astype(np.uint8)
         nows = np.arange(1, k + 1, dtype=np.int32)
+        rows = jnp.asarray(K.pack_rows(slots, counts))
         s1 = K.init_bucket_state(n)
-        s1, bits1 = K.acquire_scan_compact_bits(
-            s1, jnp.asarray(slots), jnp.asarray(counts), jnp.asarray(nows),
-            jnp.float32(4.0), jnp.float32(0.1))
+        s1, out = K.acquire_scan(s1, rows, jnp.asarray(nows),
+                                 jnp.float32(4.0), jnp.float32(0.1))
         s2 = K.init_bucket_state(n)
-        s2, bits2 = K.acquire_scan_fused_bits(
-            s2, jnp.asarray(K.pack_compact5(slots, counts)),
-            jnp.asarray(nows), jnp.float32(4.0), jnp.float32(0.1))
-        np.testing.assert_array_equal(np.asarray(bits1), np.asarray(bits2))
+        s2, bits = K.acquire_scan(s2, rows, jnp.asarray(nows),
+                                  jnp.float32(4.0), jnp.float32(0.1),
+                                  verdict_only=True)
+        np.testing.assert_array_equal(np.asarray(bits),
+                                      _bits(_scan_out(out)[0]))
         np.testing.assert_allclose(np.asarray(s1.tokens),
                                    np.asarray(s2.tokens), rtol=1e-6)
 
     def test_pack_compact5_layout(self):
-        import numpy as np
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
         slots = np.array([0, 1, 255, 65536, (1 << 24) + 7, -1], np.int32)
         counts = np.array([1, 2, 3, 4, 5, 0], np.uint8)
-        fused = K.pack_compact5(slots, counts)
+        fused = K.pack_rows(slots, counts)
         assert fused.shape == (6, 5)
         # LE i32 reassembly from bytes 0-3, count in byte 4.
         back = fused[:, :4].copy().view("<i4").reshape(-1)
         np.testing.assert_array_equal(back, slots)
         np.testing.assert_array_equal(fused[:, 4], counts)
-
-
-class TestAcquireScanPacked24:
-    def test_matches_sequential_unit_batches(self):
-        import numpy as np
-        import jax.numpy as jnp
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
-        rng = np.random.default_rng(11)
-        n, b, k = 200, 32, 3
-        slots = rng.integers(0, n, (k, b)).astype(np.int32)
-        slots[1, :4] = 9  # duplicates within one batch
-        nows = np.arange(1, k + 1, dtype=np.int32) * 7
-
-        s1 = K.init_bucket_state(n)
-        s1, granted, remaining = K.acquire_scan_packed24(
-            s1, jnp.asarray(K.pack_slots24(slots)), jnp.asarray(nows),
-            jnp.float32(3.0), jnp.float32(0.25))
-
-        s2 = K.init_bucket_state(n)
-        for i in range(k):
-            s2, g2, r2 = K.acquire_batch(
-                s2, jnp.asarray(slots[i]), jnp.ones((b,), jnp.int32),
-                jnp.ones((b,), bool), jnp.int32(nows[i]), jnp.float32(3.0),
-                jnp.float32(0.25))
-            np.testing.assert_array_equal(np.asarray(granted[i]),
-                                          np.asarray(g2))
-            np.testing.assert_allclose(np.asarray(remaining[i]),
-                                       np.asarray(r2), rtol=1e-6)
-        np.testing.assert_allclose(np.asarray(s1.tokens),
-                                   np.asarray(s2.tokens), rtol=1e-6)
-
-    def test_sentinel_rows_are_padding(self):
-        import numpy as np
-        import jax.numpy as jnp
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
-        slots = np.array([[2, K.SLOT24_PAD, 4]], np.int32)
-        s = K.init_bucket_state(8)
-        s, granted, _ = K.acquire_scan_packed24(
-            s, jnp.asarray(K.pack_slots24(slots)),
-            jnp.asarray([1], np.int32), jnp.float32(5.0), jnp.float32(0.1))
-        assert list(np.asarray(granted[0])) == [True, False, True]
-        # Padding touched nothing: only slots 2 and 4 exist.
-        assert list(np.nonzero(np.asarray(s.exists))[0]) == [2, 4]
-
-    def test_pack_roundtrip_at_boundaries(self):
-        import numpy as np
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
-        vals = np.array([0, 1, 255, 256, 65535, 65536, (1 << 24) - 2,
-                         K.SLOT24_PAD], np.int32)
-        packed = K.pack_slots24(vals)
-        restored = (packed[..., 0].astype(np.int32)
-                    | (packed[..., 1].astype(np.int32) << 8)
-                    | (packed[..., 2].astype(np.int32) << 16))
-        np.testing.assert_array_equal(restored, vals)
+        # A count over a byte widens every row to an LE i32 count.
+        wide = np.array([1, 2, 256, 70_000, 2**31 - 1, 0], np.int64)
+        rows8 = K.pack_rows(slots, wide)
+        assert rows8.shape == (6, 8)
+        np.testing.assert_array_equal(
+            rows8[:, :4].copy().view("<i4").reshape(-1), slots)
+        np.testing.assert_array_equal(
+            rows8[:, 4:].copy().view("<i4").reshape(-1), wide)
 
 
 class TestWindowAcquireScanCompact:
     def test_matches_sequential_window_batches(self):
-        import numpy as np
-        import jax.numpy as jnp
-        from distributedratelimiting.redis_tpu.ops import kernels as K
-
         rng = np.random.default_rng(13)
         n, b, k = 64, 16, 3
         slots = rng.integers(0, n, (k, b)).astype(np.int32)
@@ -499,9 +447,10 @@ class TestWindowAcquireScanCompact:
         nows = np.array([10, 40, 90], np.int32)
 
         s1 = K.init_window_state(n)
-        s1, granted, _ = K.window_acquire_scan_compact(
-            s1, jnp.asarray(slots), jnp.asarray(counts), jnp.asarray(nows),
+        s1, out = K.window_acquire_scan(
+            s1, jnp.asarray(K.pack_rows(slots, counts)), jnp.asarray(nows),
             jnp.float32(4.0), jnp.int32(32))
+        granted, _ = _scan_out(out)
 
         s2 = K.init_window_state(n)
         for i in range(k):
@@ -509,10 +458,61 @@ class TestWindowAcquireScanCompact:
                 s2, jnp.asarray(slots[i]), jnp.asarray(counts[i], jnp.int32),
                 jnp.asarray(slots[i] >= 0), jnp.int32(nows[i]),
                 jnp.float32(4.0), jnp.int32(32))
-            np.testing.assert_array_equal(np.asarray(granted[i]),
-                                          np.asarray(g2))
+            np.testing.assert_array_equal(granted[i], np.asarray(g2))
         np.testing.assert_allclose(np.asarray(s1.curr_count),
                                    np.asarray(s2.curr_count), rtol=1e-6)
+
+
+@pytest.mark.parametrize("verdict_only", [False, True])
+@pytest.mark.parametrize("width", [5, 8])
+@pytest.mark.parametrize("kind", ["bucket", "sliding", "fixed"])
+def test_scan_matches_sequential_batches(kind, width, verdict_only):
+    """Each scan kernel, in each row width and verdict form, decides K
+    batches as K sequential batch calls do: grants, remaining and the
+    table, with in-batch duplicates, padding rows and zero-permit
+    probes."""
+    rng = np.random.default_rng(29)
+    n, b, k = 70_000, 32, 4
+    slots = rng.integers(0, n, (k, b)).astype(np.int32)
+    slots[:, :6] = rng.integers(0, 4, (k, 6))  # duplicates, across batches
+    slots[1, 10:14] = -1
+    counts = rng.integers(0, 120, (k, b))
+    if width == 8:
+        counts[0, 0], counts[2, 3] = 300, 70_000
+    rows = K.pack_rows(slots, counts)
+    assert rows.shape[-1] == width
+    nows = np.array([100, 100, 700, 5_000], np.int32)
+    if kind == "bucket":
+        state = K.init_bucket_state(n)
+        params = (jnp.float32(150.0), jnp.float32(0.25))
+        scan, batch, static = K.acquire_scan, K.acquire_batch, {}
+    else:
+        state = K.init_window_state(n)
+        params = (jnp.float32(150.0), jnp.int32(1024))
+        scan, batch = K.window_acquire_scan, K.window_acquire_batch
+        static = {"interpolate": kind == "sliding"}
+
+    got_state, out = scan(jax.tree.map(jnp.copy, state), jnp.asarray(rows),
+                          jnp.asarray(nows), *params,
+                          verdict_only=verdict_only, **static)
+    want_g, want_r = [], []
+    for i in range(k):
+        state, g, r = batch(state, jnp.asarray(slots[i]),
+                            jnp.asarray(counts[i], jnp.int32),
+                            jnp.asarray(slots[i] >= 0), jnp.int32(nows[i]),
+                            *params, **static)
+        want_g.append(np.asarray(g))
+        want_r.append(np.asarray(r))
+    want_g = np.stack(want_g)
+    assert want_g.any() and not want_g[slots >= 0].all()
+    if verdict_only:
+        np.testing.assert_array_equal(np.asarray(out), _bits(want_g))
+    else:
+        granted, remaining = _scan_out(out)
+        np.testing.assert_array_equal(granted, want_g)
+        np.testing.assert_allclose(remaining, np.stack(want_r), rtol=1e-6)
+    for a, b_ in zip(got_state, state):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-6)
 
 
 # -- write-back: one unique write per slot vs the four-scatter form -------
@@ -716,11 +716,10 @@ class TestWriteBackMatchesFourScatter:
             for pos, (slot, count) in zip(_layout(rows, where), rows):
                 slots[k, pos], counts[k, pos] = slot, count
         nows = np.array([now for now, _ in launches], np.int32)
-        fused = jnp.asarray(K.pack_compact5(slots, counts))
-        kernel = (K.acquire_scan_fused_bits if bits
-                  else K.acquire_scan_fused_packed)
-        new, out = kernel(_state(_seed_state(n)), fused, jnp.asarray(nows),
-                          jnp.float32(CAP), jnp.float32(RATE))
+        new, out = K.acquire_scan(
+            _state(_seed_state(n)), jnp.asarray(K.pack_rows(slots, counts)),
+            jnp.asarray(nows), jnp.float32(CAP), jnp.float32(RATE),
+            verdict_only=bits)
         ref, out_ref = _oracle_scan(_state(_seed_state(n)), jnp.asarray(slots),
                                     jnp.asarray(counts), jnp.asarray(nows))
         if bits:

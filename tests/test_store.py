@@ -327,6 +327,17 @@ class TestBulkAcquire:
             ["big", "big", "small"], [300, 300, 1], 500.0, 1.0)
         assert [bool(g) for g in res.granted] == [True, False, True]
 
+    @pytest.mark.parametrize("fixed", [False, True])
+    def test_window_bulk_oversized_counts(self, clock, fixed):
+        """Counts over a byte take the scan kernel's 8-byte rows on the
+        window path too (coalescing off, so the scanned plan runs)."""
+        dev = device_store(clock, max_batch=8, coalesce_duplicates=False)
+        res = dev.window_acquire_many_blocking(
+            ["big", "big", "small"], [300, 300, 1], 500.0, 1.0, fixed=fixed)
+        assert dev.metrics.bulk_plans_scanned == 1
+        assert [bool(g) for g in res.granted] == [True, False, True]
+        np.testing.assert_array_equal(res.remaining, [200.0, 200.0, 499.0])
+
     def test_bulk_result_indexing_and_iter(self, clock):
         dev = device_store(clock)
         res = dev.acquire_many_blocking(["x", "y"], [1, 9], 5.0, 1.0)
@@ -813,3 +824,21 @@ class TestLaunchWidths:
             jax.monitoring.unregister_event_duration_listener(on_event)
         assert dev.metrics.launches > 12
         assert compiles == []
+
+    def test_pregrow_warms_the_served_bulk_kernel(self, clock):
+        """After the pregrow warm at the doubled size and the grow, a
+        32-chunk bulk call in either verdict form finds its scan kernel
+        compiled."""
+        from distributedratelimiting.redis_tpu.ops import kernels as K
+
+        dev = device_store(clock, max_batch=16, coalesce_duplicates=False)
+        table = dev._table(5.0, 1.0)
+        table._warm_for_size(2 * table.n_slots)
+        table._grow()
+        entries = K.acquire_scan._cache_size()
+        keys = [f"k{i % 40}" for i in range(32 * 16)]
+        for with_remaining in (True, False):
+            dev.acquire_many_blocking(keys, [1] * len(keys), 5.0, 1.0,
+                                      with_remaining=with_remaining)
+        assert dev.metrics.launches == 2
+        assert K.acquire_scan._cache_size() == entries

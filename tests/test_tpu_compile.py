@@ -69,14 +69,6 @@ def hbm_fits(compiled, limit=16 * 2**30):
     return mem
 
 
-def test_acquire_scan_packed24(one_chip):
-    k, b = 32, 8192
-    compiled = K.acquire_scan_packed24.lower(
-        bucket_state(N, one_chip), sds((k, b, 3), jnp.uint8, one_chip),
-        sds((k,), jnp.int32, one_chip), *scalars(one_chip)).compile()
-    hbm_fits(compiled)
-
-
 @pytest.mark.parametrize("n", [N, SMOKE_SLOTS])
 def test_acquire_batch_packed(one_chip, n):
     compiled = K.acquire_batch_packed.lower(
@@ -85,14 +77,16 @@ def test_acquire_batch_packed(one_chip, n):
     hbm_fits(compiled)
 
 
-@pytest.mark.parametrize("kernel", [K.acquire_scan_fused_bits,
-                                    K.acquire_scan_fused_packed])
-def test_bulk_scan_at_smoke_size(one_chip, kernel):
-    """The bulk-load kernels chip_smoke.py runs: K=32 batches of 4096."""
-    compiled = kernel.lower(
+@pytest.mark.parametrize("verdict_only", [True, False],
+                         ids=["bits", "remaining"])
+def test_bulk_scan_at_smoke_size(one_chip, verdict_only):
+    """The bulk-load kernel chip_smoke.py runs, in both verdict forms:
+    K=32 batches of 4096."""
+    compiled = K.acquire_scan.lower(
         bucket_state(SMOKE_SLOTS, one_chip),
         sds((32, 4096, 5), jnp.uint8, one_chip),
-        sds((32,), jnp.int32, one_chip), *scalars(one_chip)).compile()
+        sds((32,), jnp.int32, one_chip), *scalars(one_chip),
+        verdict_only=verdict_only).compile()
     hbm_fits(compiled)
 
 
@@ -105,7 +99,7 @@ def test_write_back_stays_in_place(one_chip, n, width, in_order):
     scatters land in the table (tokens, last_ts, exists on a new key) and
     nothing copies it. ``tokens`` and ``last_ts`` take sorted indices only
     where the table holds under SORTED_WRITE_SLOTS_PER_ROW slots a row."""
-    compiled = K.acquire_scan_fused_packed.lower(
+    compiled = K.acquire_scan.lower(
         bucket_state(n, one_chip),
         sds((1, width, 5), jnp.uint8, one_chip),
         sds((1,), jnp.int32, one_chip), *scalars(one_chip)).compile()

@@ -2,7 +2,7 @@
 
 drl-check (PR 4) lints the AST and drl-verify (PR 14) model-checks the
 protocol state machines; this tool closes the remaining gap — what the
-~46 ``@jax.jit`` kernels in ``ops/`` actually **compile to**. It
+~36 ``@jax.jit`` kernels in ``ops/`` actually **compile to**. It
 discovers every jitted kernel and its runtime launch sites via ast,
 rebuilds representative operands from the signatures and the packed
 layouts, traces each kernel to jaxpr + lowered StableHLO under

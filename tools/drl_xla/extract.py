@@ -7,8 +7,8 @@ decorators in the four ``ops/`` modules, launch sites in the two
 *structural* failure — exit 2, never a fake clean. Shapes are derived
 from each kernel's signature plus the packed-operand layouts the bodies
 themselves encode (``packed[3]`` ⇒ a 4-row flush operand,
-``_unpack_compact5`` ⇒ the 5-byte fused layout, ``[..., 2]`` off an
-``astype`` alias ⇒ the packed24 3-byte rows): an operand the deriver
+``_unpack_rows`` ⇒ the scan kernels' 5-byte rows, ``[..., 2]`` off an
+``astype`` alias ⇒ 3-byte trailing rows): an operand the deriver
 cannot place is an :class:`ExtractionError`, not a skip.
 
 Tracing happens under ``JAX_PLATFORMS=cpu``. The properties the
@@ -41,19 +41,19 @@ __all__ = [
 #: slots. Small on purpose — op COUNTS, aliasing, and cache entries are
 #: shape-independent for these kernels (everything is vectorized over
 #: B/N; nothing unrolls per element), and small shapes keep the full
-#: 46-kernel trace in seconds. N != B so an aval match between a table
+#: 36-kernel trace in seconds. N != B so an aval match between a table
 #: leaf and an output is never a batch-array coincidence.
 DIMS = {"B": 8, "K": 2, "N": 64}
 
-#: ops/ holds 46 jitted kernels today (33 kernels.py + 12
+#: ops/ holds 36 jitted kernels today (23 kernels.py + 12
 #: fp_directory.py + 1 pallas). The floor is the drl-verify
 #: extractor-richness posture: fewer extracted kernels means the
 #: extractor went blind (decorator refactor, file move), and a blind
 #: extractor must fail loudly (exit 2), not report a clean ledger.
-KERNEL_FLOOR = 40
+KERNEL_FLOOR = 30
 #: runtime/store.py + runtime/fp_store.py dispatch those kernels from
-#: ~45 call sites today; same posture.
-LAUNCH_SITE_FLOOR = 25
+#: 39 call sites today; same posture.
+LAUNCH_SITE_FLOOR = 17
 
 OPS_FILES = ("ops/kernels.py", "ops/fp_directory.py",
              "ops/bucket_math.py", "ops/pallas_kernels.py")
@@ -252,7 +252,7 @@ def launch_sites(root: pathlib.Path, decls: "list[KernelDecl]", *,
 #: Fused-operand unpack helpers whose layout is part of the wire
 #: contract (bytes-per-decision): helper name → (trailing dim, dtype).
 _HELPER_LAYOUTS = {
-    "_unpack_compact5": (5, "uint8"),    # pack_compact5: u8[..., 5]
+    "_unpack_rows": (5, "uint8"),        # pack_rows:    u8[..., 5 | 8]
     "_unpack_fp12": (3, "uint32"),       # pack_fp12:    u32[..., 3]
 }
 
@@ -427,7 +427,7 @@ def _build_args(decl: KernelDecl, module, tree: ast.Module,
             else:
                 args.append(arr(True, np.bool_, (n,)))
             leaves.append((pname, table))
-        elif pname in ("packed", "fused", "fused_k"):
+        elif pname in ("packed", "fused", "fused_k", "rows"):
             layout = _operand_layout(tree, func, pname)
             if layout is None:
                 raise ExtractionError(
