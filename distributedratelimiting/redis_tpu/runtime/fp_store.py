@@ -163,14 +163,15 @@ class _FpTable:
         )
 
     # -- kernel bindings (the window subclass swaps these) ------------------
+    # Decision operands reach the kernels as numpy arrays: jit copies them
+    # up inside the call, the launch's one host→device crossing.
     def _call_batch(self, kpair, counts, valid, now):
         """Run one fused resolve+decide batch, updating the table in
         place; returns ``(granted, remaining, resolved)`` device handles.
         Caller holds the store lock (donated buffers)."""
         self.fp, self.state, granted, remaining, resolved = (
             F.fp_acquire_batch(
-                self.fp, self.state, jnp.asarray(kpair),
-                jnp.asarray(counts), jnp.asarray(valid), jnp.int32(now),
+                self.fp, self.state, kpair, counts, valid, now,
                 self.cap_dev, self.rate_dev,
                 probe_window=self.probe_window, rounds=self.rounds))
         return granted, remaining, resolved
@@ -180,8 +181,7 @@ class _FpTable:
         :func:`~.ops.fp_directory.pack_fp12` operand up, one
         ``f32[K, 2, B]`` result down. Caller holds the store lock."""
         self.fp, self.state, out = F.fp_acquire_scan_fused(
-            self.fp, self.state, jnp.asarray(fused), jnp.asarray(nows),
-            self.cap_dev, self.rate_dev,
+            self.fp, self.state, fused, nows, self.cap_dev, self.rate_dev,
             probe_window=self.probe_window, rounds=self.rounds)
         return out
 
@@ -189,8 +189,7 @@ class _FpTable:
         """Verdict-only bulk dispatch: one operand up, granted+resolved
         bit-planes down (2 bits/decision). Caller holds the store lock."""
         self.fp, self.state, bits = F.fp_acquire_scan_fused_bits(
-            self.fp, self.state, jnp.asarray(fused), jnp.asarray(nows),
-            self.cap_dev, self.rate_dev,
+            self.fp, self.state, fused, nows, self.cap_dev, self.rate_dev,
             probe_window=self.probe_window, rounds=self.rounds)
         return bits
 
@@ -200,12 +199,13 @@ class _FpTable:
         """One fused resolve+acquire dispatch; returns device handles."""
         store = self.store
         with store._lock, annotate("drl/store.dispatch"):
-            now = store.now_ticks_checked()
+            now = np.int32(store.now_ticks_checked())
             out = self._call_batch(kpair, counts, valid, now)
             n_valid = int(valid.sum())
             if self.dirty_rows is not None:
                 self.dirty_rows += n_valid
-            store.metrics.record_launch(len(valid), n_valid)
+            store.metrics.record_launch(len(valid), n_valid, kpair, counts,
+                                        valid, now)
         return out
 
     def _postprocess(self, granted_np, remaining_np, resolved_np,
@@ -297,8 +297,9 @@ class _FpTable:
                 call = (self._call_scan_fused_bits
                         if not with_remaining and b % 8 == 0
                         else self._call_scan_fused)
-                outs.append((call(fused.reshape(k, b, 3), nows), take))
-                store.metrics.record_launch(k * b, take)
+                fused = fused.reshape(k, b, 3)
+                outs.append((call(fused, nows), take))
+                store.metrics.record_launch(k * b, take, fused, nows)
                 pos += take
         return outs
 
@@ -610,8 +611,7 @@ class _FpWindowTable(_FpTable):
     def _call_batch(self, kpair, counts, valid, now):
         self.fp, self.state, granted, remaining, resolved = (
             F.fp_window_acquire_batch(
-                self.fp, self.state, jnp.asarray(kpair),
-                jnp.asarray(counts), jnp.asarray(valid), jnp.int32(now),
+                self.fp, self.state, kpair, counts, valid, now,
                 self.limit_dev, self.window_dev,
                 probe_window=self.probe_window, rounds=self.rounds,
                 interpolate=not self.fixed))
@@ -619,16 +619,14 @@ class _FpWindowTable(_FpTable):
 
     def _call_scan_fused(self, fused, nows):
         self.fp, self.state, out = F.fp_window_acquire_scan_fused(
-            self.fp, self.state, jnp.asarray(fused), jnp.asarray(nows),
-            self.limit_dev, self.window_dev,
+            self.fp, self.state, fused, nows, self.limit_dev, self.window_dev,
             probe_window=self.probe_window, rounds=self.rounds,
             interpolate=not self.fixed)
         return out
 
     def _call_scan_fused_bits(self, fused, nows):
         self.fp, self.state, bits = F.fp_window_acquire_scan_fused_bits(
-            self.fp, self.state, jnp.asarray(fused), jnp.asarray(nows),
-            self.limit_dev, self.window_dev,
+            self.fp, self.state, fused, nows, self.limit_dev, self.window_dev,
             probe_window=self.probe_window, rounds=self.rounds,
             interpolate=not self.fixed)
         return bits

@@ -614,10 +614,10 @@ class BucketStoreServer:
         reg.register_numeric_dict(
             "store", "store metrics",
             lambda: metrics.snapshot() if metrics is not None else None,
-            counters={"launches", "rows_processed", "rows_valid", "sweeps",
-                      "slots_evicted", "rows_coalesced", "pregrows",
-                      "fp_unresolved", "bulk_plans_grouped",
-                      "bulk_plans_scanned"})
+            counters={"launches", "dispatch_transfers", "rows_processed",
+                      "rows_valid", "sweeps", "slots_evicted",
+                      "rows_coalesced", "pregrows", "fp_unresolved",
+                      "bulk_plans_grouped", "bulk_plans_scanned"})
         reg.register_numeric_dict(
             "tier0", "tier-0 admission cache",
             lambda: (self._native.tier0_stats()

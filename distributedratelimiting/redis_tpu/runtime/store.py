@@ -856,9 +856,9 @@ class _PackedLaunchMixin:
                 packed[2] = now
                 packed[3, :n] = [g[4] for g in groups]
                 packed[4, :n] = [g[2] for g in groups]
-                out = self._launch_grouped(jnp.asarray(packed))
+                out = self._launch_grouped(packed)
             n_reqs = sum(g[2] for g in groups)
-            self.store.metrics.record_launch(b, n)
+            self.store.metrics.record_launch(b, n, packed)
             self.store.metrics.rows_coalesced += n_reqs - n
             return out
 
@@ -869,7 +869,7 @@ class _PackedLaunchMixin:
         store lock at an unpredictable point mid-serving."""
         packed = np.full((5, self.store.max_batch), -1, np.int32)
         packed[1:] = 0
-        jax.block_until_ready(self._launch_grouped(jnp.asarray(packed)))
+        jax.block_until_ready(self._launch_grouped(packed))
 
     # -- growth de-cliffing -------------------------------------------------
     def _maybe_pregrow(self) -> None:
@@ -946,12 +946,12 @@ class _PackedLaunchMixin:
                 s[:take] = slots[pos:pos + take]
                 c = np.zeros((k * w,), np.uint8 if compact else np.int32)
                 c[:take] = np.minimum(counts_np[pos:pos + take], 2**31 - 1)
+                rows = K.pack_rows(s.reshape(k, w), c.reshape(k, w))
                 nows = np.full((k,), now, np.int32)
                 out = self._launch_scan_chunk(
-                    K.pack_rows(s.reshape(k, w), c.reshape(k, w)), nows,
-                    not with_remaining and w % 8 == 0)
+                    rows, nows, not with_remaining and w % 8 == 0)
                 outs.append((out, take))
-                self.store.metrics.record_launch(k * w, take)
+                self.store.metrics.record_launch(k * w, take, rows, nows)
                 pos += take
         return outs
 
@@ -959,9 +959,9 @@ class _PackedLaunchMixin:
                            verdict_only: bool):
         """One chunk's scanned dispatch — returns a device handle whose
         layout ``_gather_bulk`` understands (u8 bit-packed grants or
-        ``f32[K, 2, B]``)."""
-        self.state, out = self._scan(self.state, jnp.asarray(rows),
-                                     jnp.asarray(nows), verdict_only)
+        ``f32[K, 2, B]``). The operands stay numpy: jit copies them up
+        inside the call, the launch's one host→device crossing."""
+        self.state, out = self._scan(self.state, rows, nows, verdict_only)
         return out
 
     def _scan(self, state, rows, nows, verdict_only: bool):
@@ -1059,9 +1059,9 @@ class _PackedLaunchMixin:
                 packed[1, :m] = gcounts[pos:pos + m]
                 packed[2] = now
                 packed[4, :m] = np.minimum(lengths[pos:pos + m], 2**31 - 1)
-                out = self._launch_grouped(jnp.asarray(packed))
+                out = self._launch_grouped(packed)
                 outs.append((out, m))
-                self.store.metrics.record_launch(w, m)
+                self.store.metrics.record_launch(w, m, packed)
         self.store.metrics.rows_coalesced += n - len(gslots)
 
         def gather() -> BulkAcquireResult:
@@ -1216,11 +1216,10 @@ class _DeviceTable(_PackedLaunchMixin):
         for w in self._launch_widths():
             packed = np.full((5, w), -1, np.int32)
             packed[1:] = 0
-            state, out = K.acquire_batch_packed(
-                state, jnp.asarray(packed[:4]), cap, rate)
+            state, out = K.acquire_batch_packed(state, packed[:4], cap, rate)
             if self.store.coalesce_duplicates:
                 state, out = K.acquire_batch_packed_grouped(
-                    state, jnp.asarray(packed), cap, rate)
+                    state, packed, cap, rate)
             state, out = self._warm_scan(state, 1, w)
         jax.block_until_ready(out)
         return state
@@ -1228,9 +1227,9 @@ class _DeviceTable(_PackedLaunchMixin):
     def _warm_scan(self, state: K.BucketState, k: int, w: int):
         """Run the bulk scan kernel once on ``k × w`` padding rows, in
         both verdict forms (bits only where ``w % 8 == 0``)."""
-        rows = jnp.asarray(K.pack_rows(np.full((k, w), -1, np.int32),
-                                       np.zeros((k, w), np.uint8)))
-        nows = jnp.zeros((k,), jnp.int32)
+        rows = K.pack_rows(np.full((k, w), -1, np.int32),
+                           np.zeros((k, w), np.uint8))
+        nows = np.zeros((k,), np.int32)
         for verdict_only in (False, True) if w % 8 == 0 else (False,):
             state, out = self._scan(state, rows, nows, verdict_only)
         return state, out
@@ -1345,9 +1344,9 @@ class _DeviceTable(_PackedLaunchMixin):
             now = self.store.now_ticks_checked()
             packed = _build_packed(reqs, slots, b, now)
             self.state, out = K.acquire_batch_packed(
-                self.state, jnp.asarray(packed), self.cap_dev, self.rate_dev,
+                self.state, packed, self.cap_dev, self.rate_dev,
             )
-            self.store.metrics.record_launch(b, len(reqs))
+            self.store.metrics.record_launch(b, len(reqs), packed)
             return out
 
     # -- bulk decision path (chunk loop shared via _PackedLaunchMixin) -----
@@ -1469,14 +1468,14 @@ class _DeviceWindowTable(_PackedLaunchMixin):
         packed = np.full((4, b), -1, np.int32)
         packed[1:] = 0
         state, out = K.window_acquire_batch_packed(
-            state, jnp.asarray(packed), self.limit_dev, self.window_dev,
+            state, packed, self.limit_dev, self.window_dev,
             interpolate=not self.fixed)
         state, _ = K.sweep_windows(state, jnp.int32(0), self.window_dev)
         if self.store.coalesce_duplicates:
             packed5 = np.full((5, b), -1, np.int32)
             packed5[1:] = 0
             state, out = K.window_acquire_batch_packed_grouped(
-                state, jnp.asarray(packed5), self.limit_dev, self.window_dev,
+                state, packed5, self.limit_dev, self.window_dev,
                 interpolate=not self.fixed)
         jax.block_until_ready(out)
 
@@ -1556,10 +1555,10 @@ class _DeviceWindowTable(_PackedLaunchMixin):
             packed = _build_packed(reqs, slots, b,
                                    self.store.now_ticks_checked())
             self.state, out = K.window_acquire_batch_packed(
-                self.state, jnp.asarray(packed), self.limit_dev,
-                self.window_dev, interpolate=not self.fixed,
+                self.state, packed, self.limit_dev, self.window_dev,
+                interpolate=not self.fixed,
             )
-            self.store.metrics.record_launch(b, len(reqs))
+            self.store.metrics.record_launch(b, len(reqs), packed)
             return out
 
     # -- bulk path (chunk loop shared via _PackedLaunchMixin) --------------

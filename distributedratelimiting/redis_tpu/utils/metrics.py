@@ -40,6 +40,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Mapping
 
+import numpy as np
+
 
 class LatencyHistogram:
     """Log-spaced buckets from 1µs to ~70s (factor 1.25, 82 buckets).
@@ -254,6 +256,10 @@ class StoreMetrics:
     """Per-store (device) counters: kernel launches and batch occupancy."""
 
     launches: int = 0
+    # Operands of served decision launches that were device arrays when
+    # handed to the jitted kernel, each a host→device transfer of its own
+    # before the call (numpy operands cross inside it): reads 0.
+    dispatch_transfers: int = 0
     rows_processed: int = 0
     rows_valid: int = 0
     sweeps: int = 0
@@ -302,8 +308,13 @@ class StoreMetrics:
     # excluded from snapshot() (not a number).
     flight_recorder: object | None = None
 
-    def record_launch(self, batch_rows: int, valid_rows: int) -> None:
+    def record_launch(self, batch_rows: int, valid_rows: int,
+                      *operands) -> None:
+        """One launch; ``operands``: what a served decision launch
+        handed its jitted kernel."""
         self.launches += 1
+        self.dispatch_transfers += sum(
+            not isinstance(o, (np.ndarray, np.generic)) for o in operands)
         self.rows_processed += batch_rows
         self.rows_valid += valid_rows
 
@@ -314,6 +325,7 @@ class StoreMetrics:
     def snapshot(self) -> dict:
         return {
             "launches": self.launches,
+            "dispatch_transfers": self.dispatch_transfers,
             "rows_processed": self.rows_processed,
             "rows_valid": self.rows_valid,
             "batch_occupancy": self.batch_occupancy,

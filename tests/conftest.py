@@ -33,3 +33,20 @@ import pytest
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def transfers(monkeypatch):
+    """Names of the separate host→device transfers made while the test
+    runs (calls of ``jnp.asarray``, ``jnp.array`` and ``jax.device_put``);
+    clear it before the section under test."""
+    import jax.numpy as jnp
+
+    made: list[str] = []
+    for mod, name in ((jnp, "asarray"), (jnp, "array"), (jax, "device_put")):
+        def counted(*a, _real=getattr(mod, name), _name=name, **k):
+            made.append(_name)
+            return _real(*a, **k)
+
+        monkeypatch.setattr(mod, name, counted)
+    return made

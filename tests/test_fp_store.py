@@ -802,3 +802,89 @@ class TestServedKeyForm:
             assert int(h[i]) == fp_store._fp64_py(name)
             assert int(h[i]) == int(native[j, 0]) | int(native[j, 1]) << 32
         assert len(np.unique(h)) == n
+
+
+class TestOneCrossingPerLaunch:
+    """The fingerprint store's decision launches hand their kernels host
+    (numpy) operands: jit copies them up inside the call, with no
+    ``jnp.asarray`` or ``jax.device_put`` before it."""
+
+    @pytest.mark.parametrize("with_remaining", [True, False])
+    def test_bulk_call_sends_no_separate_transfer(self, transfers,
+                                                  with_remaining):
+        store = FingerprintBucketStore(n_slots=2**15, clock=ManualClock())
+        keys = [f"k{i}" for i in range(4096)]
+
+        async def main():
+            await store.acquire_many(keys, [1] * 4096, 5.0, 1.0,
+                                     with_remaining=with_remaining)
+            launches = store.metrics.launches
+            transfers.clear()
+            res = await store.acquire_many(keys, [1] * 4096, 5.0, 1.0,
+                                           with_remaining=with_remaining)
+            assert transfers == []
+            assert res.granted.all()
+            assert store.metrics.launches == launches + 1
+            await store.aclose()
+
+        run(main())
+        assert store.metrics.fp_unresolved == 0
+        assert store.metrics.dispatch_transfers == 0
+
+    def test_scalar_flush_sends_no_separate_transfer(self, transfers):
+        store = FingerprintBucketStore(n_slots=1024, clock=ManualClock(),
+                                       max_delay_s=0.005)
+
+        async def main():
+            await store.acquire("warm", 1, 5.0, 1.0)
+            launches = store.metrics.launches
+            transfers.clear()
+            got = await asyncio.gather(
+                *(store.acquire("hot", 1, 3.0, 1.0) for _ in range(8)))
+            assert transfers == []
+            assert [r.granted for r in got] == [True] * 3 + [False] * 5
+            assert store.metrics.launches > launches
+            await store.aclose()
+
+        run(main())
+        assert store.metrics.dispatch_transfers == 0
+
+    def test_numpy_operands_decide_like_device_operands(self):
+        """Seeded bulk calls with duplicate keys, mixed counts, counts
+        above 255 and zero-permit probes: the same grants and remainders
+        as a table that sends each operand up with ``jnp.asarray``."""
+        from distributedratelimiting.redis_tpu.runtime import fp_store
+
+        class AsarrayTable(fp_store._FpTable):
+            def _call_scan_fused(self, fused, nows):
+                return super()._call_scan_fused(jnp.asarray(fused),
+                                                jnp.asarray(nows))
+
+            def _call_scan_fused_bits(self, fused, nows):
+                return super()._call_scan_fused_bits(jnp.asarray(fused),
+                                                     jnp.asarray(nows))
+
+        class AsarrayStore(FingerprintBucketStore):
+            _TABLE_CLS = AsarrayTable
+
+        clock = ManualClock()
+        store = FingerprintBucketStore(n_slots=1024, clock=clock)
+        ref = AsarrayStore(n_slots=1024, clock=clock)
+        g = np.random.default_rng(29)
+        for _ in range(4):
+            n = int(g.integers(200, 600))
+            keys = [f"k{i}" for i in g.integers(0, 120, n)]
+            counts = [int(c) for c in g.choice([0, 1, 2, 7, 300], n)]
+            for with_remaining in (True, False):
+                got = store.acquire_many_blocking(
+                    keys, counts, 400.0, 50.0, with_remaining=with_remaining)
+                want = ref.acquire_many_blocking(
+                    keys, counts, 400.0, 50.0, with_remaining=with_remaining)
+                np.testing.assert_array_equal(got.granted, want.granted)
+                if with_remaining:
+                    np.testing.assert_array_equal(got.remaining,
+                                                  want.remaining)
+                assert got.granted.any() and not got.granted.all()
+            clock.advance_seconds(0.5)
+        assert store.metrics.fp_unresolved == 0
+        assert store.metrics.dispatch_transfers == 0

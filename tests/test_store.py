@@ -842,3 +842,152 @@ class TestLaunchWidths:
                                       with_remaining=with_remaining)
         assert dev.metrics.launches == 2
         assert K.acquire_scan._cache_size() == entries
+
+
+class TestOneCrossingPerLaunch:
+    """A served decision launch hands its jitted kernel host (numpy)
+    operands, which jit copies up inside the call: no ``jnp.asarray`` or
+    ``jax.device_put`` before it, and no new signature after the table's
+    warm-up."""
+
+    N = 4096
+
+    def _keys(self, tag=""):
+        return [f"k{tag}{i}" for i in range(self.N)]
+
+    @pytest.mark.parametrize("with_remaining", [True, False])
+    def test_bulk_call_sends_no_separate_transfer(self, clock, transfers,
+                                                  with_remaining):
+        dev = device_store(clock, n_slots=2**14)
+        keys = self._keys()
+
+        async def main():
+            await dev.acquire_many(keys, [1] * self.N, 5.0, 1.0,
+                                   with_remaining=with_remaining)
+            launches = dev.metrics.launches
+            transfers.clear()
+            res = await dev.acquire_many(keys, [1] * self.N, 5.0, 1.0,
+                                         with_remaining=with_remaining)
+            assert transfers == []
+            assert res.granted.all()
+            assert dev.metrics.launches == launches + 1
+            await dev.aclose()
+
+        run(main())
+        assert dev.metrics.dispatch_transfers == 0
+
+    def test_scalar_flush_sends_no_separate_transfer(self, clock, transfers):
+        """A flush of distinct keys (one row each) and one of a hot key
+        (the grouped kernel)."""
+        dev = device_store(clock, n_slots=1024, max_delay_s=0.005)
+
+        async def main():
+            await dev.acquire("warm", 1, 5.0, 1.0)
+            launches = dev.metrics.launches
+            transfers.clear()
+            distinct = await asyncio.gather(
+                *(dev.acquire(f"k{i}", 1, 5.0, 1.0) for i in range(8)))
+            hot = await asyncio.gather(
+                *(dev.acquire("hot", 1, 3.0, 1.0) for _ in range(8)))
+            assert transfers == []
+            assert all(r.granted for r in distinct)
+            assert [r.granted for r in hot] == [True] * 3 + [False] * 5
+            assert dev.metrics.launches > launches
+            assert dev.metrics.rows_coalesced > 0
+            await dev.aclose()
+
+        run(main())
+        assert dev.metrics.dispatch_transfers == 0
+
+    def test_first_served_calls_hit_the_warmed_signatures(self, clock):
+        """After the tables are built, a 4,096-key bulk call in both
+        verdict forms and 16-row flushes (per-row and grouped) of the
+        bucket table, and a grouped flush of the window table, add no
+        entry to any kernel's dispatch cache (a table size of its own, so
+        no other test's calls fill the entries first)."""
+        from distributedratelimiting.redis_tpu.ops import kernels as K
+
+        dev = device_store(clock, n_slots=12_345, max_delay_s=0.005)
+        dev._table(5.0, 1.0)
+        dev._wtable(4.0, 1.0)
+        kernels = (K.acquire_scan, K.acquire_batch_packed,
+                   K.acquire_batch_packed_grouped,
+                   K.window_acquire_batch_packed_grouped)
+        entries = [k._cache_size() for k in kernels]
+        keys = self._keys()
+
+        async def main():
+            for with_remaining in (True, False):
+                await dev.acquire_many(keys, [1] * self.N, 5.0, 1.0,
+                                       with_remaining=with_remaining)
+            await asyncio.gather(
+                *(dev.acquire(f"s{i}", 1, 5.0, 1.0) for i in range(8)))
+            await asyncio.gather(
+                *(dev.acquire("hot", 1, 5.0, 1.0) for _ in range(8)))
+            await asyncio.gather(
+                *(dev.window_acquire("w", 1, 4.0, 1.0) for _ in range(8)))
+            await dev.aclose()
+
+        run(main())
+        assert dev.metrics.rows_processed == 2 * self.N + 2 * 16 + 4096
+        assert [k._cache_size() for k in kernels] == entries
+
+    def test_numpy_operands_decide_like_device_operands(self, clock):
+        """Seeded bulk calls with duplicate keys, mixed counts, counts
+        above 255 (the 8-byte rows) and zero-permit probes, through both
+        plans: the same grants and remainders as a table that sends each
+        operand up with ``jnp.asarray`` before the call."""
+        import jax.numpy as jnp
+
+        from distributedratelimiting.redis_tpu.runtime import store as S
+
+        class AsarrayTable(S._DeviceTable):
+            def _launch_scan_chunk(self, rows, nows, verdict_only):
+                return super()._launch_scan_chunk(
+                    jnp.asarray(rows), jnp.asarray(nows), verdict_only)
+
+            def _launch_grouped(self, packed):
+                return super()._launch_grouped(jnp.asarray(packed))
+
+        class AsarrayStore(DeviceBucketStore):
+            _TABLE_CLS = AsarrayTable
+
+        dev = device_store(clock, n_slots=1024)
+        ref = AsarrayStore(clock=clock, n_slots=1024, counter_slots=16)
+        g = np.random.default_rng(29)
+        plans = []
+        for call in range(6):
+            n = int(g.integers(200, 600))
+            keys = [f"k{i}" for i in g.integers(0, 120, n)]
+            if call % 2:   # one count per key: the grouped plan
+                counts = [int(c) for c in g.integers(0, 40, 120)]
+                counts = [counts[int(k[1:])] for k in keys]
+            else:          # mixed counts, some above 255, zero probes
+                counts = [int(c) for c in g.choice([0, 1, 2, 7, 300], n)]
+            for with_remaining in (True, False):
+                before = dev.metrics.bulk_plans_grouped
+                got = dev.acquire_many_blocking(
+                    keys, counts, 400.0, 50.0, with_remaining=with_remaining)
+                want = ref.acquire_many_blocking(
+                    keys, counts, 400.0, 50.0, with_remaining=with_remaining)
+                plans.append(dev.metrics.bulk_plans_grouped - before)
+                np.testing.assert_array_equal(got.granted, want.granted)
+                if with_remaining:
+                    np.testing.assert_array_equal(got.remaining,
+                                                  want.remaining)
+                assert got.granted.any() and not got.granted.all()
+            clock.advance_seconds(0.5)
+        assert 0 < sum(plans) < len(plans)
+        assert dev.metrics.dispatch_transfers == 0
+
+
+def test_record_launch_counts_operands_already_on_the_device():
+    import jax.numpy as jnp
+
+    from distributedratelimiting.redis_tpu.utils.metrics import StoreMetrics
+
+    m = StoreMetrics()
+    m.record_launch(16, 3, np.zeros(4, np.int32), np.int32(7))
+    m.record_launch(16, 3, jnp.zeros(4, jnp.int32), np.zeros(1, np.int32))
+    assert (m.launches, m.dispatch_transfers) == (2, 1)
+    assert m.snapshot()["dispatch_transfers"] == 1
